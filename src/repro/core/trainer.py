@@ -550,7 +550,7 @@ def make_dist_eval(cfg: M.GCNConfig, dc: DistConfig):
                                   jax.random.PRNGKey(0), False)
         _, correct, cnt = M.loss_and_metrics(logits, wd.labels, wd.eval_mask)
         return (jax.lax.psum(correct, dc.psum_axes),
-                jax.lax.psum(cnt, dc.psum_axes))
+                jax.lax.psum(cnt, dc.psum_axes), logits)
     return worker_fn
 
 
@@ -604,8 +604,8 @@ class DistributedTrainer:
             self._eval = jax.jit(jax.vmap(
                 worker_eval, axis_name=dc.axis_name, in_axes=(None, 0)))
         elif mode == "shard_map":
+            from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
             if mesh is None:
                 raise ValueError("shard_map mode needs a mesh")
             self.mesh = mesh
@@ -613,7 +613,6 @@ class DistributedTrainer:
             # updated params will carry from epoch 2 on (they mix with the
             # step's P()-replicated grads); host-resident epoch-1 params
             # would compile a second executable for the same step.
-            from jax.sharding import NamedSharding
             _rep = NamedSharding(mesh, P())
             self.params = jax.device_put(self.params, _rep)
             self.opt_state = jax.device_put(self.opt_state, _rep)
@@ -624,6 +623,11 @@ class DistributedTrainer:
             else:
                 data_axes = dc.axis_name
             self._data_axes = data_axes
+            # Worker-axis sharding of wd and the halo cache.
+            self._data_sharding = NamedSharding(mesh, P(data_axes))
+            # Each device holds its own worker's graph: placed once here,
+            # the step never re-shards it from the default device.
+            self.wd = jax.device_put(wd, self._data_sharding)
             spec_data = jax.tree_util.tree_map(lambda _: P(data_axes), wd)
 
             def _squeeze(tree):
@@ -644,25 +648,27 @@ class DistributedTrainer:
                     c = jax.tree_util.tree_map(lambda x: x[None], c)
                     return g, m, c
 
-                self._step = jax.jit(shard_map(
+                self._step = jax.jit(jax.shard_map(
                     step_sm, mesh=mesh,
                     in_specs=(P(), spec_data, P(), cache_spec, P()),
-                    out_specs=(P(), P(), cache_spec), check_rep=False))
+                    out_specs=(P(), P(), cache_spec), check_vma=False))
             else:
                 def step_sm(params, wdata, key):
                     return worker_step(params, _squeeze(wdata), key)
 
-                self._step = jax.jit(shard_map(
+                self._step = jax.jit(jax.shard_map(
                     step_sm, mesh=mesh,
                     in_specs=(P(), spec_data, P()),
-                    out_specs=(P(), P()), check_rep=False))
+                    out_specs=(P(), P()), check_vma=False))
 
             def eval_sm(params, wdata):
-                return worker_eval(params, _squeeze(wdata))
+                correct, cnt, logits = worker_eval(params, _squeeze(wdata))
+                return correct, cnt, logits[None]
 
-            self._eval = jax.jit(shard_map(
+            self._eval = jax.jit(jax.shard_map(
                 eval_sm, mesh=mesh,
-                in_specs=(P(), spec_data), out_specs=(P(), P()), check_rep=False))
+                in_specs=(P(), spec_data),
+                out_specs=(P(), P(), P(data_axes)), check_vma=False))
         else:
             raise ValueError(mode)
 
@@ -688,11 +694,7 @@ class DistributedTrainer:
             # Commit the zero-fill to the same sharding the step
             # returns its cache with; otherwise epoch 2's differently
             # laid-out inputs compile a second executable.
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
-            sh = NamedSharding(self.mesh, P(self._data_axes))
-            self._cache = jax.tree_util.tree_map(
-                lambda a: jax.device_put(a, sh), self._cache)
+            self._cache = jax.device_put(self._cache, self._data_sharding)
 
     def _step_args(self, key) -> tuple:
         """Assemble the _step argument tuple."""
@@ -736,8 +738,7 @@ class DistributedTrainer:
         sh = {k: jax.tree_util.tree_map(lambda _: rep, v)
               for k, v in template.items() if k != "cache"}
         if "cache" in template:
-            data = NamedSharding(self.mesh, P(self._data_axes))
-            sh["cache"] = jax.tree_util.tree_map(lambda _: data,
+            sh["cache"] = jax.tree_util.tree_map(lambda _: self._data_sharding,
                                                  template["cache"])
         return sh
 
@@ -772,14 +773,19 @@ class DistributedTrainer:
 
         The halo cache is passed as ShapeDtypeStructs so lowering a
         delayed-comm schedule at production scale never materializes the
-        (potentially huge) stale buffers.
+        (potentially huge) stale buffers. Under shard_map they carry the
+        cache's worker-axis sharding, so the compiled program is the one
+        :meth:`train_epoch` dispatches.
         """
         key = key if key is not None else jax.random.PRNGKey(0)
         if self.use_cache and self._cache is None:
             dims = self.cfg.dims()[: self.cfg.num_layers]
             rows = self.schedule.cache_rows(self.wd)
             lead = self.wd.x.shape[:-2]
-            cache = [tuple(jax.ShapeDtypeStruct((*lead, r, f), jnp.float32)
+            sh = (self._data_sharding if self.mode == "shard_map"
+                  else None)
+            cache = [tuple(jax.ShapeDtypeStruct((*lead, r, f), jnp.float32,
+                                                sharding=sh)
                            for r in rows) for f in dims]
             return self._step.lower(self.params, self.wd, key, cache,
                                     jnp.asarray(0, jnp.int32))
@@ -801,9 +807,15 @@ class DistributedTrainer:
         return {k: float(v) for k, v in metrics.items()}
 
     def evaluate(self) -> float:
-        correct, cnt = self._eval(self.params, self.wd)
+        correct, cnt, _ = self._eval(self.params, self.wd)
         correct, cnt = self._unreplicate((correct, cnt))
         return float(correct) / max(float(cnt), 1.0)
+
+    def predict(self) -> jax.Array:
+        """Eval-mode logits (fp32 sync halo, no dropout) of every worker's
+        local rows, with wd's leading worker axes; the program is the one
+        :meth:`evaluate` runs."""
+        return self._eval(self.params, self.wd)[2]
 
     def fit(self, epochs: int, log_every: int = 0) -> List[Dict]:
         history = []

@@ -1,15 +1,14 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels target TPU and are validated via the interpreter). On a real TPU
-backend the same calls lower to Mosaic.
+The kernels compile with Mosaic on a TPU and run in the Pallas interpreter
+elsewhere (their ``interpret=None`` default); ``use_kernel=False`` is the
+caller's explicit request for the jnp reference.
 """
 
 from __future__ import annotations
 
 from typing import Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -24,21 +23,12 @@ from repro.kernels.seg_aggregate import (  # noqa: F401  (re-exported API)
 )
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def aggregate(x, ell_idx, ell_w, *, use_kernel: bool = True, **kw):
-    """Neighbour aggregation: Pallas kernel (TPU target) or jnp fallback.
-
-    The jnp fallback is used for unaligned shapes and inside traced code
-    where interpret-mode pallas would be slow on CPU.
+    """Neighbour aggregation: the Pallas kernel (any shape; the wrapper
+    pads), or the jnp reference when the caller passes ``use_kernel=False``.
     """
-    r, k = ell_idx.shape
-    n, f = x.shape
-    aligned = (f % 128 == 0) and (r % 8 == 0)
-    if use_kernel and aligned:
-        return seg_aggregate(x, ell_idx, ell_w, interpret=not _on_tpu(), **kw)
+    if use_kernel:
+        return seg_aggregate(x, ell_idx, ell_w, **kw)
     return ref.seg_aggregate_ref(x, ell_idx, ell_w)
 
 
@@ -89,7 +79,7 @@ def quantize_pack(x, noise, *, bits: int = 2, use_kernel: bool = True):
     rows, feat = x.shape
     aligned = (rows % 4 == 0) and (feat % per_word == 0)
     if use_kernel and aligned:
-        return quant_pack(x, noise, bits=bits, interpret=not _on_tpu())
+        return quant_pack(x, noise, bits=bits)
     return ref.quant_pack_ref(x, noise, bits)
 
 
@@ -97,6 +87,5 @@ def dequantize_unpack(packed, zero, scale, *, bits: int = 2, feat: int,
                       use_kernel: bool = True):
     rows = packed.shape[0]
     if use_kernel and rows % 4 == 0:
-        return dequant_unpack(packed, zero, scale, bits=bits, feat=feat,
-                              interpret=not _on_tpu())
+        return dequant_unpack(packed, zero, scale, bits=bits, feat=feat)
     return ref.dequant_unpack_ref(packed, zero, scale, bits, feat)

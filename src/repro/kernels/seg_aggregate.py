@@ -3,37 +3,46 @@
 The paper optimizes ``index_add``/``SpMM`` on CPUs by (1) clustering sources
 by sorted destination, (2) loop reordering for register reuse of the
 destination row, (3) shape-adaptive vector-register inner kernels, and
-(4) 2-D dynamic parallelism. The TPU translation (DESIGN.md §3):
+(4) 2-D dynamic parallelism. The TPU translation:
 
 * *clustering/sorting* → the host builds a **blocked-ELL** layout: CSR sorted
   by destination is padded to ``K`` neighbour slots per row, so each grid
-  step owns a contiguous ``(BR, BF)`` destination tile.
-* *register reuse of dst* → the destination tile lives in VMEM for the whole
-  ``K``-slot loop; each slot contributes one gathered ``(BR, BF)`` source
-  tile (the accumulate never leaves VMEM).
-* *shape-adaptive inner kernel* → ``BF`` is a multiple of 128 (lane width)
-  and ``BR`` a multiple of 8 (sublane), chosen per feature width.
-* *2-D parallelism* → grid = (row blocks × feature blocks); nnz balance is
-  done at partition time (FLOP-based load balancing moved to preprocessing).
+  step owns a contiguous block of destination rows.
+* *register reuse of dst* → the ``(BR, F)`` destination tile is the output
+  block, resident in VMEM across every ``K``-chunk grid step that adds into
+  it (float32 accumulation).
+* *shape-adaptive inner kernel* → the slot chunk ``KC`` is the smallest
+  power of two covering ``K`` (at most 16) and ``BR = 1024 / KC``, so every
+  grid step gathers 1024 source rows whatever the degree class.
+* *2-D parallelism* → grid = (row tiles × K chunks); nnz balance is done at
+  partition time (FLOP-based load balancing moved to preprocessing).
 
-VMEM budget: the source matrix is feature-tiled (``[N, BF]`` resident per
-step). This is deliberate: the operator runs on *partition-local* graphs —
-the paper's own hierarchical partitioning bounds ``N`` per worker, so the
-local feature slab fits VMEM at production scale (e.g. 8k rows x 128 lanes
-x 4 B = 4 MB < 16 MB). Validated with interpret=True on CPU.
+Gather: the source features stay in HBM (``memory_space=pl.ANY``). Each
+grid step receives its 1024 source-row ids as an SMEM block and issues one
+row DMA per slot into a VMEM tile, then accumulates the weighted rows.
+VMEM use is bounded by the tile (``<= 3 x 1024 x F x 4`` bytes), not by the
+number of source rows ``N``, so a real partition's feature slab never has
+to fit on chip. Rows are DMA'd from a ``[N, 1, F]`` view of the features:
+XLA lays that out with a ``(1, 128)`` tile, which makes a single row an
+aligned DMA (a row of the ``[N, F]`` view is an eighth of an ``(8, 128)``
+tile, which Mosaic refuses to slice).
+
+Widths that are not multiples of 128 (Table 2's 100 and 602) and row counts
+that are not multiples of the tile are zero-padded inside the wrapper;
+padded slots carry weight 0 and add exact zeros. Under ``vmap`` the batch
+of workers folds into one call over the concatenated graphs (source ids
+offset per worker), so the kernel itself never sees a batch axis.
 
 Degree-bucketed layout (the production hot path)
 ------------------------------------------------
 
 A single-K ELL pads every row to the *max* degree, which on power-law
-graphs inflates memory and FLOPs by orders of magnitude (the reason the
-kernel used to sit outside the training loop). The production layout
-(``graph.structure.bucketed_ell_from_csr``) instead splits rows into
-degree classes on a growth-2 ladder K in {1, 2, 4, 8, ...}: a row of
-degree d pads to the smallest K >= d, wasting < d slots, so **total
-padded slots < 2 x nnz on any graph** (plus a per-bucket row-alignment
-sliver for the kernel's 8-row sublane tile). :func:`bucketed_aggregate`
-runs one ``seg_aggregate`` per bucket — each a dense, perfectly regular
+graphs inflates memory and FLOPs by orders of magnitude. The production
+layout (``graph.structure.bucketed_ell_from_csr``) instead splits rows into
+degree classes on a growth-2 ladder K in {1, 2, 4, 8, ...}: a row of degree
+d pads to the smallest K >= d, wasting < d slots, so **total padded slots
+< 2 x nnz on any graph**. :func:`bucketed_aggregate` runs one
+``seg_aggregate`` per bucket — each a dense, perfectly regular
 gather/accumulate — and scatters the R (not nnz) bucket outputs into the
 destination rows.
 
@@ -45,6 +54,12 @@ same bucketed kernel over it, instead of letting XLA transpose the
 forward gather into the scatter-add access pattern the paper's operator
 exists to avoid. The cotangent of the layout arrays is structurally zero
 (edge weights are preprocessing constants).
+
+Realization policy (``use_kernel``): ``"auto"`` runs the compiled kernel on
+a TPU and the XLA reference (``kernels.ref.seg_aggregate_ref``) elsewhere;
+``True`` forces the kernel (interpreted off-TPU, for tests); ``False``
+forces the reference. Nothing falls back silently: on a TPU the reference
+runs only when the caller passes ``False``.
 """
 
 from __future__ import annotations
@@ -56,76 +71,154 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 
 
-DEFAULT_BLOCK_ROWS = 8
-DEFAULT_BLOCK_FEAT = 128
+# Source rows gathered per grid step. The step's row ids arrive as one SMEM
+# block of a 1-D int32 array, which XLA tiles in units of 1024.
+SLOTS_PER_STEP = 1024
+LANES = 128
+MAX_BLOCK_K = 16
 
 
-def _seg_aggregate_kernel(idx_ref, w_ref, x_ref, out_ref, *, block_k: int):
-    """One (BR, BF) destination tile: accumulate K gathered source tiles."""
-    br, k_total = idx_ref.shape
-    acc = jnp.zeros(out_ref.shape, dtype=jnp.float32)
-
-    def body(kb, acc):
-        # Process neighbour slots in chunks of block_k to bound gather size.
-        start = kb * block_k
-        idx = jax.lax.dynamic_slice(idx_ref[...], (0, start), (br, block_k))
-        w = jax.lax.dynamic_slice(w_ref[...], (0, start), (br, block_k))
-        gathered = x_ref[idx.reshape(-1), :]  # [(BR*block_k), BF] row gather
-        gathered = gathered.reshape(br, block_k, -1)
-        return acc + jnp.einsum(
-            "rk,rkf->rf", w.astype(jnp.float32), gathered.astype(jnp.float32)
-        )
-
-    num_kb = pl.cdiv(k_total, block_k)
-    acc = jax.lax.fori_loop(0, num_kb, body, acc)
-    out_ref[...] = acc.astype(out_ref.dtype)
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_rows", "block_feat", "block_k", "interpret")
-)
+def _block_k(k: int) -> int:
+    """Slot chunk per grid step: the smallest power of two >= K, capped."""
+    return min(MAX_BLOCK_K, 1 << max(k - 1, 0).bit_length())
+
+
+def _seg_aggregate_kernel(idx_ref, w_ref, x_hbm, out_ref, buf, sem, *,
+                          block_rows: int, block_k: int):
+    """One (BR, F) destination tile, one chunk of KC neighbour slots.
+
+    ``idx_ref`` holds the chunk's BR*KC source ids slot-major (slot k of
+    row r at ``k * BR + r``); ``out_ref`` stays resident across chunks and
+    accumulates in float32.
+    """
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    def row_copy(j, src):
+        return pltpu.make_async_copy(
+            x_hbm.at[pl.ds(src, 1)],
+            buf.at[j // block_rows, pl.ds(j % block_rows, 1)],
+            sem.at[0])
+
+    def start(j, carry):
+        row_copy(j, idx_ref[j]).start()
+        return carry
+
+    def wait(j, carry):
+        row_copy(0, 0).wait()  # every copy moves one row: same byte count
+        return carry
+
+    slots = block_rows * block_k
+    jax.lax.fori_loop(0, slots, start, 0)
+    jax.lax.fori_loop(0, slots, wait, 0)
+    w = w_ref[...]
+    acc = out_ref[...]
+    for k in range(block_k):
+        acc = acc + w[:, k:k + 1] * buf[k].reshape(block_rows, -1)
+    out_ref[...] = acc
+
+
+def _vmem_limit(block_rows: int, fp: int) -> int:
+    """Scoped-VMEM request: the gathered rows plus double-buffered output
+    and (lane-padded) weight blocks, with headroom for Mosaic's own
+    temporaries."""
+    need = 4 * (SLOTS_PER_STEP * fp + 2 * block_rows * (fp + LANES))
+    return max(32 * 2**20, 2 * need)
+
+
+def _seg_aggregate_pallas(x, ell_idx, ell_w, *, block_k: Optional[int],
+                          interpret: bool):
+    n, f = x.shape
+    r, k = ell_idx.shape
+    if r == 0 or k == 0 or n == 0:
+        return jnp.zeros((r, f), x.dtype)
+    kc = _block_k(k) if block_k is None else int(block_k)
+    if kc < 1 or SLOTS_PER_STEP % kc or SLOTS_PER_STEP // kc < 8:
+        raise ValueError(f"block_k={kc} must be a power of two <= "
+                         f"{SLOTS_PER_STEP // 8}")
+    br = SLOTS_PER_STEP // kc
+    fp, kp, rp = _round_up(f, LANES), _round_up(k, kc), _round_up(r, br)
+    nt, nk = rp // br, kp // kc
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, fp - f)))
+    idx = jnp.pad(ell_idx.astype(jnp.int32), ((0, rp - r), (0, kp - k)))
+    w = jnp.pad(ell_w.astype(jnp.float32), ((0, rp - r), (0, kp - k)))
+    # Step (i, c) reads idx block i*nk + c, slot-major within the block.
+    idx = idx.reshape(nt, br, nk, kc).transpose(0, 2, 3, 1).reshape(-1)
+    w = w.reshape(rp, nk, kc).transpose(1, 0, 2)  # [nk, rp, kc]
+    out = pl.pallas_call(
+        functools.partial(_seg_aggregate_kernel, block_rows=br, block_k=kc),
+        grid=(nt, nk),
+        in_specs=[
+            pl.BlockSpec((SLOTS_PER_STEP,), lambda i, c: (i * nk + c,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, br, kc), lambda i, c: (c, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((br, fp), lambda i, c: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp, fp), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((kc, br, 1, fp), jnp.float32),
+                        pltpu.SemaphoreType.DMA((1,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(br, fp)),
+        interpret=interpret,
+        name="seg_aggregate",
+    )(idx, w, xp.reshape(n, 1, fp))
+    return out[:r, :f].astype(x.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _batchable(block_k: Optional[int], interpret: bool):
+    """``_seg_aggregate_pallas`` with a vmap rule that folds the batch into
+    one call: B graphs over B feature slabs are one graph over the
+    concatenated slab, with each worker's source ids offset by its slab."""
+    fn = jax.custom_batching.custom_vmap(functools.partial(
+        _seg_aggregate_pallas, block_k=block_k, interpret=interpret))
+
+    @fn.def_vmap
+    def _rule(axis_size, in_batched, x, idx, w):
+        x_b, idx_b, w_b = in_batched
+        bcast = lambda a, b: a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
+        idx, w = bcast(idx, idx_b), bcast(w, w_b)
+        r, k = idx.shape[1:]
+        if x_b:
+            n = x.shape[1]
+            idx = idx + (jnp.arange(axis_size, dtype=idx.dtype) * n)[:, None, None]
+            x = x.reshape(axis_size * n, x.shape[2])
+        out = fn(x, idx.reshape(axis_size * r, k), w.reshape(axis_size * r, k))
+        return out.reshape(axis_size, r, out.shape[-1]), True
+
+    return fn
+
+
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def seg_aggregate(
     x: jax.Array,        # [N, F]
     ell_idx: jax.Array,  # [R, K] int32
     ell_w: jax.Array,    # [R, K] f32 (0 padding)
     *,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
-    block_feat: int = DEFAULT_BLOCK_FEAT,
-    block_k: int = 16,
-    interpret: bool = True,
+    block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """out[r] = sum_k ell_w[r,k] * x[ell_idx[r,k]] via pallas_call."""
-    n, f = x.shape
-    r, k = ell_idx.shape
-    if f % block_feat or r % block_rows:
-        raise ValueError(
-            f"shape ({r},{k})x({n},{f}) not aligned to blocks ({block_rows},{block_feat})"
-        )
-    block_k = min(block_k, k)
-    if k % block_k:
-        # Pad the slot axis so the in-kernel dynamic_slice never clamps
-        # (clamped slices would re-read earlier slots and double count).
-        pad = block_k - k % block_k
-        ell_idx = jnp.pad(ell_idx, ((0, 0), (0, pad)))
-        ell_w = jnp.pad(ell_w, ((0, 0), (0, pad)))
-        k += pad
-    grid = (r // block_rows, f // block_feat)
-    return pl.pallas_call(
-        functools.partial(_seg_aggregate_kernel, block_k=block_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, k), lambda i, j: (i, 0)),   # idx tile
-            pl.BlockSpec((block_rows, k), lambda i, j: (i, 0)),   # weight tile
-            pl.BlockSpec((n, block_feat), lambda i, j: (0, j)),   # src feature slab
-        ],
-        out_specs=pl.BlockSpec((block_rows, block_feat), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, f), x.dtype),
-        interpret=interpret,
-    )(ell_idx, ell_w, x)
+    """out[r] = sum_k ell_w[r,k] * x[ell_idx[r,k]] via pallas_call.
+
+    ``block_k`` overrides the slot chunk per grid step (a power of two up
+    to 128; default from K). ``interpret=None`` compiles on a TPU and
+    interprets elsewhere; ``True`` interprets only when a caller asks.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _batchable(block_k, bool(interpret))(x, ell_idx, ell_w)
 
 
 # --------------------------------------------------------------------------
@@ -165,20 +258,17 @@ def device_bucketed(stacked, squeeze: bool = False) -> DeviceBucketedEll:
 
 
 def _use_kernel(policy) -> bool:
-    """Resolve the kernel policy: True/False force, "auto" = TPU only (the
-    interpret-mode kernel is correct but far too slow for a CPU hot path)."""
+    """Resolve the kernel policy: True/False force, "auto" = the compiled
+    kernel on a TPU, the XLA reference elsewhere (the interpreted kernel is
+    correct but far too slow for a CPU hot path)."""
     if policy == "auto":
         return jax.default_backend() == "tpu"
     return bool(policy)
 
 
 def _bucket_matvec(x: jax.Array, b: DeviceEllBucket, kernel: bool) -> jax.Array:
-    r, k = b.idx.shape
-    aligned = (x.shape[-1] % DEFAULT_BLOCK_FEAT == 0
-               and r % DEFAULT_BLOCK_ROWS == 0)
-    if kernel and aligned:
-        return seg_aggregate(x, b.idx, b.w,
-                             interpret=jax.default_backend() != "tpu")
+    if kernel:
+        return seg_aggregate(x, b.idx, b.w)
     return ref.seg_aggregate_ref(x, b.idx, b.w)
 
 

@@ -36,7 +36,6 @@ from repro.launch.hlo_stats import parse_collectives
 from repro.launch.input_specs import input_specs
 from repro.launch.mesh import make_production_mesh
 from repro.models.transformer import forward_train, serve_step, train_step
-from repro.sharding.compat import mesh_context
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
@@ -161,7 +160,7 @@ def _specs_for(arch, shape, mesh, num_microbatches=None):
 def _lower(arch, spec, mesh):
     window = spec["window"]
     shape = spec["shape"]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             nm = spec["num_microbatches"]
             fn = functools.partial(train_step, cfg=arch, lr=3e-4,

@@ -16,9 +16,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
+def _auto(n: int):
+    """Worker meshes are driven by shard_map with explicit in/out specs;
+    ``Auto`` axes keep the arrays' shardings out of their types, so a
+    replicated ``P()`` and the ``P(None, ...)`` a step returns compile
+    to one executable."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_worker_mesh(nworkers: int, axis: str = "workers"):
     """1-D graph-parallel mesh for the distributed GCN trainer."""
-    return jax.make_mesh((nworkers,), (axis,))
+    return jax.make_mesh((nworkers,), (axis,), axis_types=_auto(1))
 
 
 def make_hier_worker_mesh(num_groups: int, group_size: int,
@@ -29,4 +37,5 @@ def make_hier_worker_mesh(num_groups: int, group_size: int,
     (sockets of one node); jax.make_mesh's default device assignment keeps
     the trailing axis innermost, which matches typical process layouts.
     """
-    return jax.make_mesh((num_groups, group_size), (group_axis, node_axis))
+    return jax.make_mesh((num_groups, group_size), (group_axis, node_axis),
+                         axis_types=_auto(2))

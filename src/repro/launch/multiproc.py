@@ -104,6 +104,10 @@ from repro.launch.shm_store import (
 from repro.optim import adamw_init, adamw_update
 
 _THREAD_ENV = ("OMP_NUM_THREADS", "OPENBLAS_NUM_THREADS", "MKL_NUM_THREADS")
+# The fleet emulates the paper's CPU workers. A chip belongs to one
+# process, so children are pinned to the CPU and never race the parent
+# (or each other) for an accelerator.
+_FLEET_PLATFORM = "cpu"
 _BWD_KEY_FOLD = 0x5BD1  # must match exchange._quantized_exchange_bwd
 _WORKER_WAIT_S = 600.0  # mailbox spin deadline (1-core containers are slow)
 _PARENT_WAIT_S = 900.0  # parent deadline per command round
@@ -961,6 +965,7 @@ class _RankWorker:
 
     def summary(self) -> dict:
         return {"rank": self.rank,
+                "platform": jax.default_backend(),
                 "rss_before_attach": self.rss_before_attach,
                 "rss_after_attach": self.rss_after_attach,
                 "rss_after_slices": self.rss_after_slices,
@@ -1241,9 +1246,10 @@ class MultiprocRuntime:
         """Spawn (or respawn) one rank against the already-published
         segments, with the thread env partitioned across ranks."""
         threads = max(1, (os.cpu_count() or 1) // self.nprocs)
-        saved = {k: os.environ.get(k) for k in _THREAD_ENV}
-        for k in _THREAD_ENV:
-            os.environ[k] = str(threads)
+        pinned = {**{k: str(threads) for k in _THREAD_ENV},
+                  "JAX_PLATFORMS": _FLEET_PLATFORM}
+        saved = {k: os.environ.get(k) for k in pinned}
+        os.environ.update(pinned)
         try:
             parent_conn, child_conn = self._ctx.Pipe()
             p = self._ctx.Process(
@@ -1560,6 +1566,7 @@ class MultiprocRuntime:
 
     def summary(self) -> dict:
         out = {"mode": "multiproc", "nprocs": self.nprocs,
+               "platform": _FLEET_PLATFORM,
                "token": self.token, "parent_rss": rss_bytes(),
                "epoch_stats": self.epoch_stats, **self.dry_plan()}
         if self._started:

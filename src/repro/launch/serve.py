@@ -44,6 +44,9 @@ def main(argv=None) -> int:
     import numpy as np
 
     from repro.serve import ServeSpec, build_server
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     spec = ServeSpec.load(args.spec).with_overrides(args.set)
     print(f"spec: {spec.describe()}")

@@ -57,7 +57,8 @@ def run_gcn(args):
         if spec.exec.mode == "multiproc":
             smry = session.trainer.summary()
             rss = [r["rss_after_slices"] for r in smry.get("ranks", [])]
-            print(f"multiproc: {smry['nprocs']} procs, shared store "
+            print(f"multiproc: {smry['nprocs']} procs on the "
+                  f"{smry['platform'].upper()}, shared store "
                   f"{smry['store_bytes'] / 1e6:.1f} MB (one copy), "
                   f"rank RSS {[round(r / 1e6, 1) for r in rss]} MB")
     finally:
@@ -203,6 +204,8 @@ def main():
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.gcn:
         run_gcn(args)
     elif args.arch:

@@ -1,4 +1,3 @@
-from repro.sharding.compat import abstract_mesh, axis_size, mesh_context
 from repro.sharding.specs import (
     batch_spec,
     cache_specs,
@@ -13,7 +12,4 @@ __all__ = [
     "cache_specs",
     "data_axes",
     "spec_for_array",
-    "abstract_mesh",
-    "axis_size",
-    "mesh_context",
 ]

@@ -26,13 +26,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.quant.stochastic import QuantParams, dequantize, quantize
-from repro.sharding.compat import axis_size as _axis_size
 
 
 def quantized_all_to_all(x: jax.Array, axis_name: str, *, bits: int = 8,
                          key: Optional[jax.Array] = None) -> jax.Array:
     """Tiled all_to_all of a [P*R, F] buffer with quantized payload."""
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     rows, feat = x.shape
     if (rows // p) % 4:
         raise ValueError("rows per destination must be a multiple of 4")
@@ -61,7 +60,7 @@ def quantized_psum(g: jax.Array, axis_name: str, *, bits: int = 8,
     ``g``: any-shape fp32 gradient; flattened internally. Padded to
     (P * 4 * lanes) so row groups align with shards.
     """
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if key is None:
         key = jax.random.PRNGKey(1)
     key = jax.random.fold_in(key, jax.lax.axis_index(axis_name))

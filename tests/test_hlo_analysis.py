@@ -130,7 +130,6 @@ ENTRY %main (a: u4[2]) -> u4[2] {
     def test_while_loop_multiplication_end_to_end(self):
         """Compiled JAX scan with a psum inside (vmap->jit collective)."""
         mesh = jax.make_mesh((1,), ("w",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def worker(x):
@@ -138,8 +137,8 @@ ENTRY %main (a: u4[2]) -> u4[2] {
                 return c + jax.lax.psum(xi, "w"), None
             out, _ = jax.lax.scan(body, jnp.zeros_like(x[0]), x)
             return out
-        f = shard_map(worker, mesh=mesh, in_specs=(P(None),), out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(worker, mesh=mesh, in_specs=(P(None),),
+                          out_specs=P(), check_vma=False)
         txt = jax.jit(f).lower(
             jax.ShapeDtypeStruct((5, 8), jnp.float32)).compile().as_text()
         st = parse_collectives(txt)
@@ -196,7 +195,6 @@ module @jit_step {
         an all_to_all before its dot, under shard_map on 2 virtual
         devices (the conftest provides 8 host devices)."""
         mesh = jax.make_mesh((2,), ("w",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def worker(x):
@@ -205,8 +203,8 @@ module @jit_step {
             local = x[0] @ x[0].T
             return local + recv[0] @ recv[0].T
 
-        f = shard_map(worker, mesh=mesh, in_specs=(P("w"),),
-                      out_specs=P("w"), check_rep=False)
+        f = jax.shard_map(worker, mesh=mesh, in_specs=(P("w"),),
+                          out_specs=P("w"), check_vma=False)
         txt = jax.jit(f).lower(
             jax.ShapeDtypeStruct((4, 2, 8), jnp.float32)).as_text()
         order = collective_order(txt)

@@ -50,11 +50,13 @@ class TestSegAggregate:
         idx = jax.random.randint(ki, (32, 12), 0, 200)
         w = jax.random.uniform(kw, (32, 12))
         expect = ref.seg_aggregate_ref(x, idx, w)
-        for br, bf, bk in [(8, 128, 4), (16, 128, 16), (8, 256, 12), (32, 128, 3)]:
-            out = seg_aggregate(x, idx, w, block_rows=br, block_feat=bf,
-                                block_k=bk, interpret=True)
+        # block_k sets the slot chunk per grid step (and with it the row
+        # tile, 1024 / block_k): 1 and 4 chunk K=12 into many steps, 16 and
+        # 64 pad it into one.
+        for bk in (1, 4, 16, 64):
+            out = seg_aggregate(x, idx, w, block_k=bk, interpret=True)
             np.testing.assert_allclose(out, expect, rtol=1e-5, atol=1e-5,
-                                       err_msg=f"blocks ({br},{bf},{bk})")
+                                       err_msg=f"block_k={bk}")
 
     @settings(max_examples=10, deadline=None)
     @given(st.integers(1, 8), st.integers(1, 24), st.integers(0, 9999))
@@ -70,10 +72,12 @@ class TestSegAggregate:
         np.testing.assert_allclose(2.5 * out1, out2, rtol=1e-4, atol=1e-4)
 
     def test_unaligned_falls_back(self):
+        """Unaligned widths and row counts stay on the kernel: the wrapper
+        pads them (60 -> 128 lanes, 5 -> one row tile) and slices back."""
         x = jnp.ones((10, 60))       # 60 not a lane multiple
         idx = jnp.zeros((5, 3), jnp.int32)
         w = jnp.ones((5, 3))
-        out = aggregate(x, idx, w)   # dispatcher uses the jnp oracle
+        out = aggregate(x, idx, w)
         np.testing.assert_allclose(out, 3.0 * jnp.ones((5, 60)))
 
 

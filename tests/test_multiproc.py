@@ -123,6 +123,15 @@ class TestParity:
             attach_delta = r["rss_after_attach"] - r["rss_before_attach"]
             assert attach_delta < max(smry["store_bytes"], 1 << 20)
 
+    def test_fleet_runs_on_the_cpu(self, hier_run):
+        """Children are spawned with JAX_PLATFORMS=cpu: the fleet never
+        competes with its parent for an accelerator."""
+        *_, stats = hier_run
+        smry = stats["summary"]
+        assert smry["platform"] == "cpu"
+        assert [r["platform"] for r in smry["ranks"]] == ["cpu"] * len(
+            smry["ranks"])
+
 
 class TestTeardown:
     def test_normal_exit_unlinks_all_segments(self, flat_run, hier_run):

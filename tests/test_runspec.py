@@ -255,10 +255,19 @@ class TestInterBitsDefault:
         assert DistConfig(nparts=4).schedule().stages[0].bits == 0
 
 
+def _spec_files():
+    """specs/*.json with the loader each needs: serve specs (a top-level
+    ``serve`` section) are ServeSpecs, the rest RunSpecs."""
+    from repro.serve.spec import ServeSpec, is_serve_spec_dict
+    for p in sorted((ROOT / "specs").glob("*.json")):
+        serve = is_serve_spec_dict(json.loads(p.read_text()))
+        yield p, (ServeSpec if serve else RunSpec)
+
+
 class TestCheckedInSpecs:
     def test_matrix_covers_support_classes(self):
-        specs = {p.stem: RunSpec.load(p)
-                 for p in (ROOT / "specs").glob("*.json")}
+        specs = {p.stem: cls.load(p) for p, cls in _spec_files()
+                 if cls is RunSpec}
         assert len(specs) >= 5
         classes = {
             "flat_fp32": lambda s: (not s.partition.hierarchical
@@ -276,8 +285,8 @@ class TestCheckedInSpecs:
                 f"no canonical spec covers {cname}"
 
     def test_specs_round_trip_canonically(self):
-        for p in (ROOT / "specs").glob("*.json"):
-            spec = RunSpec.load(p)
+        for p, cls in _spec_files():
+            spec = cls.load(p)
             assert spec.to_json() + "\n" == p.read_text(), \
                 f"{p.name} is not in canonical to_json() form"
 

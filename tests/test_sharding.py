@@ -12,7 +12,6 @@ import pytest
 from repro.configs import ARCH_NAMES, get_arch, get_smoke_arch
 from repro.launch.hlo_stats import parse_collectives
 from repro.models import init_params
-from repro.sharding.compat import abstract_mesh
 from repro.sharding.specs import batch_spec, cache_specs, param_specs
 
 
@@ -46,7 +45,7 @@ class TestParamSpecs:
     def test_production_mesh_rules(self):
         """On a 4x4 stand-in of the production mesh, big matrices must be
         2-D sharded (TP x FSDP) and scan stacks must keep dim0 unsharded."""
-        mesh = abstract_mesh((2, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
         cfg = get_arch("tinyllama-1.1b")
         shapes = jax.eval_shape(lambda k: init_params(k, cfg),
                                 jax.random.PRNGKey(0))
@@ -67,7 +66,7 @@ class TestParamSpecs:
         assert "data" not in str(ispecs["blocks"]["attn"]["w_q"])
 
     def test_batch_spec_divisibility(self, tiny_mesh):
-        mesh = abstract_mesh((2, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
         assert batch_spec(mesh, 128)[0] in ("data", ("data",))
         assert batch_spec(mesh, 1)[0] is None  # long_500k: replicate
 
@@ -75,7 +74,7 @@ class TestParamSpecs:
 class TestCacheSpecs:
     def test_cache_seq_sharded_over_model(self):
         from repro.models import init_cache
-        mesh = abstract_mesh((2, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
         cfg = get_smoke_arch("tinyllama-1.1b")
         cache = jax.eval_shape(lambda: init_cache(cfg, 4, 128))
         specs = cache_specs(cache, mesh, 4)
