@@ -5,7 +5,6 @@
   Fig 7   -> speedup_model.py  (Eqn-8 speedup vs P, measured alpha/beta/gamma/delta)
   Figs 9/10 -> scaling.py      (epoch time w/ & w/o comm opts + measured)
   Fig 11/Table 3 -> convergence.py (FP32/Int2 x LP accuracy + cd-5 baseline)
-  Fig 12  -> breakdown.py      (time breakdown, small vs large scale)
   Serving -> serving.py        (online inference latency/QPS + bit-parity)
 
 Prints ``name,us_per_call,derived`` CSV.
@@ -18,7 +17,7 @@ import sys
 import time
 
 MODULES = ["aggregation", "comm_volume", "speedup_model", "scaling",
-           "convergence", "breakdown", "bits_ablation", "serving"]
+           "convergence", "bits_ablation", "serving"]
 
 
 def main() -> None:

@@ -279,12 +279,14 @@ def _post_wire(y: jax.Array, topo: StageTopo) -> jax.Array:
 
 def _quantized_wire(w: jax.Array, key, topo: StageTopo, bits: int) -> jax.Array:
     """Quantize a wire-level buffer, all_to_all the payload, dequantize."""
-    q, params = quantize(w, bits, key)
+    with jax.named_scope("quantize"):
+        q, params = quantize(w, bits, key)
     qr = _wire_a2a(q.astype(jnp.int32), topo)
     # fp32 (zero, scale) ride along — the paper's "params" wire term (Eqn 5).
     zr = _wire_a2a(params.zero[:, None], topo).reshape(-1)
     sr = _wire_a2a(params.scale[:, None], topo).reshape(-1)
-    return dequantize(qr, QuantParams(zr, sr))
+    with jax.named_scope("dequantize"):
+        return dequantize(qr, QuantParams(zr, sr))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))

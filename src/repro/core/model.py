@@ -82,22 +82,26 @@ def forward(
 ) -> jax.Array:
     h = x
     if cfg.label_prop:
-        emb = params["lp_embed"][jnp.clip(labels, 0, cfg.num_classes - 1)]
-        h = h + jnp.where(prop_mask[:, None], emb, 0.0)
+        with jax.named_scope("label_prop"):
+            emb = params["lp_embed"][jnp.clip(labels, 0, cfg.num_classes - 1)]
+            h = h + jnp.where(prop_mask[:, None], emb, 0.0)
     for l, p in enumerate(params["layers"]):
-        if cfg.norm == "layer":
-            h = L.layer_norm(h, p["ln_scale"], p["ln_bias"])
-        if train and cfg.dropout > 0:
-            dropout_key, sub = jax.random.split(dropout_key)
-            keep = jax.random.bernoulli(sub, 1.0 - cfg.dropout, h.shape)
-            h = jnp.where(keep, h / (1.0 - cfg.dropout), 0.0)
-        if cfg.model == "gat":
-            h = agg_fn(l, h)  # GAT fuses aggregate+update (attention needs both ends)
-        else:
-            z = agg_fn(l, h)
-            h = L.apply_update(cfg.model, p, h, z)
-        if l < cfg.num_layers - 1:
-            h = jax.nn.relu(h)
+        with jax.named_scope(f"layer{l}"):
+            if cfg.norm == "layer":
+                with jax.named_scope("norm"):
+                    h = L.layer_norm(h, p["ln_scale"], p["ln_bias"])
+            if train and cfg.dropout > 0:
+                with jax.named_scope("dropout"):
+                    dropout_key, sub = jax.random.split(dropout_key)
+                    keep = jax.random.bernoulli(sub, 1.0 - cfg.dropout, h.shape)
+                    h = jnp.where(keep, h / (1.0 - cfg.dropout), 0.0)
+            with jax.named_scope("aggregate"):
+                z = agg_fn(l, h)
+            with jax.named_scope("update"):
+                # GAT fuses aggregate+update (attention needs both ends)
+                h = z if cfg.model == "gat" else L.apply_update(cfg.model, p, h, z)
+                if l < cfg.num_layers - 1:
+                    h = jax.nn.relu(h)
     return h
 
 
@@ -105,9 +109,10 @@ def loss_and_metrics(
     logits: jax.Array, labels: jax.Array, loss_mask: jax.Array
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Masked softmax cross entropy. Returns (loss_sum, correct_sum, count)."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
-    m = loss_mask.astype(jnp.float32)
-    loss_sum = jnp.sum(nll * m)
-    correct = jnp.sum((jnp.argmax(logits, -1) == labels).astype(jnp.float32) * m)
-    return loss_sum, correct, jnp.sum(m)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
+        m = loss_mask.astype(jnp.float32)
+        loss_sum = jnp.sum(nll * m)
+        correct = jnp.sum((jnp.argmax(logits, -1) == labels).astype(jnp.float32) * m)
+        return loss_sum, correct, jnp.sum(m)

@@ -52,9 +52,10 @@ from repro.graph.structure import (
 )
 from repro.kernels import aggregate as kernel_aggregate
 from repro.kernels import bucketed_aggregate, device_bucketed
-from repro.kernels.seg_aggregate import DeviceBucketedEll
+from repro.kernels.seg_aggregate import DeviceBucketedEll, bucketed_slots
 from repro.kernels.ref import seg_aggregate_ref
 from repro.optim import adamw_init, adamw_update
+from repro.utils import trace
 
 
 # --------------------------------------------------------------------------
@@ -487,9 +488,12 @@ def _dist_forward(params, cfg: M.GCNConfig, dc: DistConfig, wd: WorkerData,
         def agg_fn(l: int, h: jax.Array) -> jax.Array:
             kq = jax.random.fold_in(key, 7919 + l) if key is not None else None
             entry = halo_cache[l] if halo_cache is not None else None
-            inflight = prog.issue(h, kq, cache_entry=entry, epoch=epoch)
-            local = _local_aggregate(h, wd, dc.agg_backend)
-            agg, ne = prog.finalize(local, inflight)
+            with jax.named_scope("exchange_issue"):
+                inflight = prog.issue(h, kq, cache_entry=entry, epoch=epoch)
+            with jax.named_scope("local"):
+                local = _local_aggregate(h, wd, dc.agg_backend)
+            with jax.named_scope("exchange_finalize"):
+                agg, ne = prog.finalize(local, inflight)
             new_cache.append(ne)
             return agg
         return agg_fn
@@ -498,6 +502,11 @@ def _dist_forward(params, cfg: M.GCNConfig, dc: DistConfig, wd: WorkerData,
     logits = M.forward(params, cfg, wd.x, wd.labels, prop_mask,
                        agg_fn_factory(kd), train=train, dropout_key=kd)
     return logits, new_cache
+
+
+@jax.jit
+def _count_nonzero(arrays) -> jax.Array:
+    return sum(jnp.count_nonzero(a) for a in arrays)
 
 
 def make_dist_train_step(cfg: M.GCNConfig, dc: DistConfig, use_cache: bool = False):
@@ -671,6 +680,27 @@ class DistributedTrainer:
                 out_specs=(P(), P(), P(data_axes)), check_vma=False))
         else:
             raise ValueError(mode)
+        self._agg_counts = self._kernel_counts()
+
+    def _kernel_counts(self) -> Dict[str, int]:
+        """Real edges (non-zero weights) and kernel slots (padding included)
+        of one epoch's bucketed aggregations: the local graph and every
+        receive scatter that has edges, each forward and backward in every
+        layer. Empty when the step aggregates no bucketed layout."""
+        if self.dc.agg_backend != "ell":
+            return {}
+        wd = self.wd
+        plans = ([wd.plan] if wd.plan is not None
+                 else [wd.hier_plan.intra, wd.hier_plan.inter])
+        layouts = [wd.ell, wd.ell_t] + [
+            lay for p in plans if p.recv_ell is not None
+            for lay in (p.recv_ell, p.recv_ell_t)]
+        workers = int(np.prod(wd.x.shape[:-2]))
+        slots = sum(bucketed_slots(lay, workers, fold=self.mode == "vmap")
+                    for lay in layouts)
+        edges = int(_count_nonzero([b.w for lay in layouts for b in lay.buckets]))
+        layers = self.cfg.num_layers
+        return {"agg.edges": layers * edges, "agg.slots": layers * slots}
 
     def _unreplicate(self, tree):
         if self.mode == "vmap":
@@ -792,19 +822,25 @@ class DistributedTrainer:
         return self._step.lower(*self._step_args(key))
 
     def train_epoch(self) -> Dict[str, float]:
-        key = jax.random.PRNGKey(1000003 + self.epoch)
-        args = self._step_args(key)
-        if self.use_cache:
-            grads, metrics, cache = self._step(*args)
-            self._cache = cache
-        else:
-            grads, metrics = self._step(*args)
-        grads = self._unreplicate(grads)
-        metrics = self._unreplicate(metrics)
-        self.params, self.opt_state = adamw_update(
-            grads, self.opt_state, self.params, self.dc.lr)
-        self.epoch += 1
-        return {k: float(v) for k, v in metrics.items()}
+        with trace.span("epoch"):
+            with trace.span("step"):
+                key = jax.random.PRNGKey(1000003 + self.epoch)
+                args = self._step_args(key)
+                if self.use_cache:
+                    grads, metrics, cache = self._step(*args)
+                    self._cache = cache
+                else:
+                    grads, metrics = self._step(*args)
+                grads = self._unreplicate(grads)
+                metrics = self._unreplicate(metrics)
+            with trace.span("optimizer"):
+                self.params, self.opt_state = adamw_update(
+                    grads, self.opt_state, self.params, self.dc.lr)
+            self.epoch += 1
+            for name, n in self._agg_counts.items():
+                trace.count(name, n)
+            with trace.span("fetch"):
+                return {k: float(v) for k, v in metrics.items()}
 
     def evaluate(self) -> float:
         correct, cnt, _ = self._eval(self.params, self.wd)

@@ -41,6 +41,7 @@ from repro.graph.structure import (
     coo_to_csr,
     transpose_csr,
 )
+from repro.utils import trace
 
 
 @dataclass
@@ -268,14 +269,17 @@ def build_partitioned_graph(
     sp, dp = part[g.src], part[g.dst]
     is_local = sp == dp
 
-    # Local graphs (reindexed to local ids, CSR by local dst).
+    # Local graphs (reindexed to local ids, CSR by local dst) and their
+    # reverse graphs, which drive the aggregation's backward pass.
     local_csr: List[CSR] = []
-    for p in range(nparts):
-        sel = is_local & (dp == p)
-        ls = local_index[g.src[sel]]
-        ld = local_index[g.dst[sel]]
-        lw = g.edge_weight[sel]
-        local_csr.append(coo_to_csr(ls, ld, lw, len(owned[p]), len(owned[p])))
+    with trace.span("csr"):
+        for p in range(nparts):
+            sel = is_local & (dp == p)
+            ls = local_index[g.src[sel]]
+            ld = local_index[g.dst[sel]]
+            lw = g.edge_weight[sel]
+            local_csr.append(coo_to_csr(ls, ld, lw, len(owned[p]), len(owned[p])))
+        local_csr_t = [transpose_csr(c) for c in local_csr]
 
     # Remote graphs per ordered pair + MVC classification.
     pair_plans: Dict[Tuple[int, int], PairPlan] = {}
@@ -345,6 +349,9 @@ def build_partitioned_graph(
         selected=strategy,
         padded_rows_per_pair=padded,
     )
+    with trace.span("ell"):
+        local_ell = [bucketed_ell_from_csr(c) for c in local_csr]
+        local_ell_t = [bucketed_ell_from_csr(c) for c in local_csr_t]
     return PartitionedGraph(
         nparts=nparts,
         part=part,
@@ -355,9 +362,8 @@ def build_partitioned_graph(
         stats=stats,
         num_nodes=g.num_nodes,
         max_owned=max_owned,
-        local_ell=[bucketed_ell_from_csr(c) for c in local_csr],
-        local_ell_t=[bucketed_ell_from_csr(transpose_csr(c))
-                     for c in local_csr],
+        local_ell=local_ell,
+        local_ell_t=local_ell_t,
     )
 
 

@@ -136,18 +136,34 @@ def _vmem_limit(block_rows: int, fp: int) -> int:
     return max(32 * 2**20, 2 * need)
 
 
+def _launch_shape(r: int, k: int, block_k: Optional[int]):
+    """(KC, BR, padded rows, padded K) of one launch over an [r, k] layout:
+    rows round up to the row tile BR = 1024 / KC, K to the chunk KC."""
+    kc = _block_k(k) if block_k is None else int(block_k)
+    if kc < 1 or SLOTS_PER_STEP % kc or SLOTS_PER_STEP // kc < 8:
+        raise ValueError(f"block_k={kc} must be a power of two <= "
+                         f"{SLOTS_PER_STEP // 8}")
+    br = SLOTS_PER_STEP // kc
+    return kc, br, _round_up(r, br), _round_up(k, kc)
+
+
+def kernel_slots(r: int, k: int) -> int:
+    """Neighbour slots one kernel launch over an [r, k] layout works
+    through, padding included (0 when the wrapper launches nothing)."""
+    if r == 0 or k == 0:
+        return 0
+    _, _, rp, kp = _launch_shape(r, k, None)
+    return rp * kp
+
+
 def _seg_aggregate_pallas(x, ell_idx, ell_w, *, block_k: Optional[int],
                           interpret: bool):
     n, f = x.shape
     r, k = ell_idx.shape
     if r == 0 or k == 0 or n == 0:
         return jnp.zeros((r, f), x.dtype)
-    kc = _block_k(k) if block_k is None else int(block_k)
-    if kc < 1 or SLOTS_PER_STEP % kc or SLOTS_PER_STEP // kc < 8:
-        raise ValueError(f"block_k={kc} must be a power of two <= "
-                         f"{SLOTS_PER_STEP // 8}")
-    br = SLOTS_PER_STEP // kc
-    fp, kp, rp = _round_up(f, LANES), _round_up(k, kc), _round_up(r, br)
+    kc, br, rp, kp = _launch_shape(r, k, block_k)
+    fp = _round_up(f, LANES)
     nt, nk = rp // br, kp // kc
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, fp - f)))
     idx = jnp.pad(ell_idx.astype(jnp.int32), ((0, rp - r), (0, kp - k)))
@@ -257,6 +273,19 @@ def device_bucketed(stacked, squeeze: bool = False) -> DeviceBucketedEll:
     ))
 
 
+def bucketed_slots(ell: DeviceBucketedEll, workers: int, fold: bool) -> int:
+    """Kernel slots of one aggregation over a stacked layout of
+    ``workers`` worker graphs: under ``vmap`` (``fold``) each bucket is one
+    launch over the workers' rows end to end, otherwise one launch per
+    worker."""
+    total = 0
+    for b in ell.buckets:
+        rows, k = b.idx.shape[-2:]
+        total += (kernel_slots(workers * rows, k) if fold
+                  else workers * kernel_slots(rows, k))
+    return total
+
+
 def _use_kernel(policy) -> bool:
     """Resolve the kernel policy: True/False force, "auto" = the compiled
     kernel on a TPU, the XLA reference elsewhere (the interpreted kernel is
@@ -281,7 +310,8 @@ def _bucketed_forward(x: jax.Array, ell: DeviceBucketedEll, out_rows: int,
     """
     out = jnp.zeros((out_rows, x.shape[-1]), x.dtype)
     for b in ell.buckets:
-        out = out.at[b.rows].add(_bucket_matvec(x, b, kernel))
+        with jax.named_scope(f"k{b.idx.shape[-1]}"):
+            out = out.at[b.rows].add(_bucket_matvec(x, b, kernel))
     return out
 
 
@@ -309,7 +339,8 @@ def _bucketed_aggregate_bwd(out_rows, in_rows, kernel, res, g):
     ell, ell_t = res
     # The transpose aggregation IS an aggregation — same bucketed access
     # pattern, reverse-graph layout.
-    dx = _bucketed_forward(g, ell_t, in_rows, kernel)
+    with jax.named_scope("agg_bwd"):
+        dx = _bucketed_forward(g, ell_t, in_rows, kernel)
     return dx, _zero_cotangents(ell), _zero_cotangents(ell_t)
 
 

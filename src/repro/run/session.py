@@ -28,6 +28,7 @@ import numpy as np
 
 import repro.run.sources as sources  # populates the registries on import
 from repro.run.spec import GRAPH_SOURCES, FEATURE_SOURCES, RunSpec
+from repro.utils import trace
 
 
 def stage_hlo_payload_bytes(rows: int, feat: int, bits: int) -> float:
@@ -339,6 +340,15 @@ class Session:
             return int(step._cache_size())
         return None
 
+    def op_scopes(self) -> Dict[str, Any]:
+        """The training step's XLA module name and, for each compiled
+        instruction in a program scope, its scope path (``layer0/update``,
+        ``layer1/aggregate/local/agg_bwd/k64/bwd``; see
+        ``utils.trace.scope_path``), read from the executable a device trace
+        shows. Lowers and compiles the step again (a persistent-cache load
+        when the cache is on), so call it outside any timed window."""
+        return trace.op_scopes(self.trainer.lower_step().compile().as_text())
+
     def describe(self) -> str:
         return self.spec.describe()
 
@@ -364,23 +374,26 @@ def build_session(spec: RunSpec, cache: Optional[BuildCache] = None
                                     prepare_distributed_host)
 
     spec = resolve_auto(spec.validate())
-    if cache is not None:
-        g, x = cache.graph(spec)
-        pg = cache.partition(spec, g)
-    else:
-        g, x = build_graph(spec)
-        pg = build_partition(spec, g)
-    hwd = prepare_distributed_host(g, x, pg)
-    if spec.exec.mode == "multiproc":
-        # The host arrays ARE the runtime's shared store; workers device-
-        # materialize their own slices, the parent never lifts anything.
-        from repro.launch.multiproc import MultiprocRuntime
-        runtime = MultiprocRuntime(spec, hwd)
-        return Session(spec, g, x, pg, hwd, None, runtime)
-    wd = _lift_worker_data(hwd)
-    dc = spec.schedule.to_dist_config(spec.partition, lr=spec.exec.lr)
-    cfg = spec.model.to_gcn_config(spec.graph, spec.schedule)
-    mesh = build_mesh(spec)
-    trainer = DistributedTrainer(cfg, dc, wd, mode=spec.exec.mode,
-                                 mesh=mesh, seed=spec.exec.seed)
+    with trace.span("build"):
+        with trace.span("graph"):
+            g, x = cache.graph(spec) if cache is not None else build_graph(spec)
+        with trace.span("partition"):
+            pg = (cache.partition(spec, g) if cache is not None
+                  else build_partition(spec, g))
+        with trace.span("host_data"):
+            hwd = prepare_distributed_host(g, x, pg)
+        if spec.exec.mode == "multiproc":
+            # The host arrays ARE the runtime's shared store; workers device-
+            # materialize their own slices, the parent never lifts anything.
+            from repro.launch.multiproc import MultiprocRuntime
+            runtime = MultiprocRuntime(spec, hwd)
+            return Session(spec, g, x, pg, hwd, None, runtime)
+        with trace.span("lift"):
+            wd = _lift_worker_data(hwd)
+        with trace.span("trainer"):
+            dc = spec.schedule.to_dist_config(spec.partition, lr=spec.exec.lr)
+            cfg = spec.model.to_gcn_config(spec.graph, spec.schedule)
+            mesh = build_mesh(spec)
+            trainer = DistributedTrainer(cfg, dc, wd, mode=spec.exec.mode,
+                                         mesh=mesh, seed=spec.exec.seed)
     return Session(spec, g, x, pg, wd, mesh, trainer)
