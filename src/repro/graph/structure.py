@@ -341,6 +341,10 @@ def bucketed_ell_from_csr(csr: CSR, min_k: int = 1,
         lo = k
         if not len(sel):
             continue
+        # Highest degree first: in every row tile the kernel takes, each
+        # slot's real rows then come before its padding (seg_aggregate
+        # fetches a slot's rows only up to the last one of nonzero weight).
+        sel = sel[np.argsort(-deg[sel], kind="stable")]
         d = deg[sel]
         offs = np.arange(int(d.sum())) - np.repeat(np.cumsum(d) - d, d)
         pos = np.repeat(csr.indptr[sel], d) + offs

@@ -19,7 +19,21 @@ destination row, (3) shape-adaptive vector-register inner kernels, and
 
 Gather: the source features stay in HBM (``memory_space=pl.ANY``). Each
 grid step receives its 1024 source-row ids as an SMEM block and issues one
-row DMA per slot into a VMEM tile, then accumulates the weighted rows.
+row DMA per slot into a VMEM tile, then accumulates the weighted rows in
+slot order. Slots of weight 0 that trail their column are not fetched: for
+each of the step's KC slot columns the kernel reduces the weight block to
+the row after the column's last nonzero weight and issues copies only up
+to there. The layout builder orders each degree bucket's rows by degree,
+highest first, so in every row tile each slot's real rows come first and
+all the ladder's padding trails. The time of a step goes to issuing copies
+and waiting on them on the scalar core, not to moving the rows, so the
+waits are taken eight rows at a time. A zero-weight slot before the tail
+is still fetched; every slot adds its row through a select on the weight,
+so a slot that was not fetched (its VMEM is stale) adds an exact zero, and
+the sum is the one a fetch-everything kernel gives for finite inputs. The
+one difference is at non-finite inputs: a NaN or Inf in a source row
+reached only through zero weights no longer turns the output NaN
+(``0 * inf``).
 VMEM use is bounded by the tile (``<= 3 x 1024 x F x 4`` bytes), not by the
 number of source rows ``N``, so a real partition's feature slab never has
 to fit on chip. Rows are DMA'd from a ``[N, 1, F]`` view of the features:
@@ -29,7 +43,7 @@ tile, which Mosaic refuses to slice).
 
 Widths that are not multiples of 128 (Table 2's 100 and 602) and row counts
 that are not multiples of the tile are zero-padded inside the wrapper;
-padded slots carry weight 0 and add exact zeros. Under ``vmap`` the batch
+padded slots carry weight 0 and are never fetched. Under ``vmap`` the batch
 of workers folds into one call over the concatenated graphs (source ids
 offset per worker), so the kernel itself never sees a batch axis.
 
@@ -81,6 +95,8 @@ from repro.kernels import ref
 SLOTS_PER_STEP = 1024
 LANES = 128
 MAX_BLOCK_K = 16
+# Row copies one semaphore wait stands for (a row tile has at least 8 rows).
+WAIT_ROWS = 8
 
 
 def _round_up(n: int, m: int) -> int:
@@ -98,33 +114,48 @@ def _seg_aggregate_kernel(idx_ref, w_ref, x_hbm, out_ref, buf, sem, *,
 
     ``idx_ref`` holds the chunk's BR*KC source ids slot-major (slot k of
     row r at ``k * BR + r``); ``out_ref`` stays resident across chunks and
-    accumulates in float32.
+    accumulates in float32, slot by slot.
     """
     @pl.when(pl.program_id(1) == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    def row_copy(j, src):
-        return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(src, 1)],
-            buf.at[j // block_rows, pl.ds(j % block_rows, 1)],
-            sem.at[0])
-
-    def start(j, carry):
-        row_copy(j, idx_ref[j]).start()
-        return carry
-
-    def wait(j, carry):
-        row_copy(0, 0).wait()  # every copy moves one row: same byte count
-        return carry
-
-    slots = block_rows * block_k
-    jax.lax.fori_loop(0, slots, start, 0)
-    jax.lax.fori_loop(0, slots, wait, 0)
     w = w_ref[...]
+    # Per slot column, the row after its last one of nonzero weight: the
+    # rows from there on are not fetched.
+    row1 = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) + 1
+    live = jnp.max(jnp.where(w != 0, row1, 0), axis=0, keepdims=True)
+
+    def fetch_column(k):
+        """Start slot k's row copies up to its column's last nonzero weight."""
+        col = k * block_rows
+        n = jnp.max(live[:, k:k + 1])
+
+        def start(r, carry):
+            pltpu.make_async_copy(x_hbm.at[pl.ds(idx_ref[col + r], 1)],
+                                  buf.at[k, pl.ds(r, 1)], sem.at[0]).start()
+            return carry
+
+        jax.lax.fori_loop(0, n, start, 0)
+        return n
+
+    def wait(rows):
+        # A wait takes the bytes of its descriptor off the semaphore: one
+        # wait on `rows` rows of the tile stands for that many row copies.
+        def body(i, carry):
+            rows_ref = buf.at[0, pl.ds(0, rows)]
+            pltpu.make_async_copy(rows_ref, rows_ref, sem.at[0]).wait()
+            return carry
+        return body
+
+    started = sum(fetch_column(k) for k in range(block_k))
+    jax.lax.fori_loop(0, started // WAIT_ROWS, wait(WAIT_ROWS), 0)
+    jax.lax.fori_loop(0, started % WAIT_ROWS, wait(1), 0)
     acc = out_ref[...]
     for k in range(block_k):
-        acc = acc + w[:, k:k + 1] * buf[k].reshape(block_rows, -1)
+        # A slot that was not fetched holds stale VMEM: select, not 0 * row.
+        wk = w[:, k:k + 1]
+        acc = acc + jnp.where(wk != 0, wk * buf[k].reshape(block_rows, -1), 0.0)
     out_ref[...] = acc
 
 

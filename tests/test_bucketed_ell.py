@@ -113,6 +113,25 @@ class TestBucketedLayout:
         assert [b.k for b in ell.buckets] == [2]
         assert ell.buckets[0].rows.tolist() == [3]
 
+    def test_bucket_rows_highest_degree_first(self):
+        """Inside a bucket rows run from highest degree down (ties in node
+        order), so each slot's padding trails its real rows in every row tile
+        the kernel takes; each row keeps its own neighbours in CSR order."""
+        g = rmat_graph(9, edge_factor=8, seed=2).mean_normalized()
+        csr = g.csr_by_dst()
+        deg = csr.row_degrees()
+        ell = bucketed_ell_from_csr(csr)
+        for b in ell.buckets:
+            d = deg[b.rows]
+            assert (np.diff(d) <= 0).all()
+            assert all((np.diff(b.rows[d == v]) > 0).all() for v in np.unique(d))
+            np.testing.assert_array_equal(
+                b.w != 0, np.arange(b.k)[None, :] < d[:, None])
+            lo = csr.indptr[b.rows]
+            for i in range(0, len(b.rows), max(1, len(b.rows) // 7)):
+                np.testing.assert_array_equal(
+                    b.idx[i, :d[i]], csr.indices[lo[i]:lo[i] + d[i]])
+
     def test_partition_stats_accounting_matches_layouts(self):
         """partition_stats' padded-slot accounting == the slots the
         partition-time layouts actually materialize."""

@@ -71,6 +71,82 @@ class TestSegAggregate:
         out2 = seg_aggregate(2.5 * x, idx, w, interpret=True)
         np.testing.assert_allclose(2.5 * out1, out2, rtol=1e-4, atol=1e-4)
 
+    @staticmethod
+    def _padded_layout(seed, n, r, k, max_deg, min_src=0, by_degree=False):
+        """Rows of degree 1..max_deg (row r has degree 1 + r % max_deg, or
+        those degrees highest first, as the bucketed layout orders them),
+        padded to K with id 0 and weight 0, as the bucketed layout pads."""
+        rng = np.random.default_rng(seed)
+        deg = 1 + np.arange(r) % max_deg
+        if by_degree:
+            deg = np.sort(deg)[::-1]
+        real = np.arange(k)[None, :] < deg[:, None]
+        idx = np.where(real, rng.integers(min_src, n, (r, k)), 0)
+        w = np.where(real, rng.uniform(0.1, 1.0, (r, k)), 0.0)
+        return idx.astype(np.int32), w.astype(np.float32)
+
+    @staticmethod
+    def _slot_order_sum(x, idx, w):
+        """float32 sum of w[r, k] * x[idx[r, k]] for k = 0..K-1, in order."""
+        acc = np.zeros((idx.shape[0], x.shape[1]), np.float32)
+        for k in range(idx.shape[1]):
+            acc = acc + w[:, k:k + 1] * x[idx[:, k]]
+        return acc
+
+    @pytest.mark.parametrize("n,f,r,k,max_deg,by_degree", [
+        (300, 256, 64, 8, 8, False),     # mixed degree inside one bucket
+        (300, 256, 200, 8, 8, True),     # the same, highest degree first
+        (40, 60, 5, 3, 3, False),        # rows round up to BR, K to KC, F to lanes
+        (200, 128, 48, 20, 16, False),   # the second K chunk is all padding
+        (150, 128, 128, 8, None, False),  # no zero weight: nothing is skipped
+    ])
+    def test_padding_slots_match_slot_order_sum(self, n, f, r, k, max_deg,
+                                                by_degree):
+        """Skipping zero-weight slots leaves the kernel's sum what it was: the
+        reference within 1e-5, and the float32 sum in slot order within one
+        ulp a slot. Not bit for bit: the interpreter runs the kernel through
+        XLA's CPU backend, which fuses some multiplies into their adds, so
+        the kernel that fetched every slot missed the plain sum too."""
+        rng = np.random.default_rng(n + r + k)
+        x = rng.standard_normal((n, f)).astype(np.float32)
+        if max_deg is None:
+            idx = rng.integers(0, n, (r, k)).astype(np.int32)
+            w = rng.uniform(0.1, 1.0, (r, k)).astype(np.float32)
+        else:
+            idx, w = self._padded_layout(r, n, r, k, max_deg,
+                                         by_degree=by_degree)
+        out = np.asarray(seg_aggregate(x, idx, w, interpret=True))
+        np.testing.assert_allclose(out, ref.seg_aggregate_ref(x, idx, w),
+                                   rtol=1e-5, atol=1e-5)
+        # Each slot rounds its product or its fused sum at most once, below
+        # one ulp of the sum of |w x|, which bounds every partial sum.
+        ulp = np.spacing(self._slot_order_sum(np.abs(x), idx, np.abs(w)))
+        assert (np.abs(out - self._slot_order_sum(x, idx, w)) <= k * ulp).all()
+
+    @pytest.mark.parametrize("by_degree", [False, True])
+    def test_zero_weight_rows_never_reach_the_sum(self, by_degree):
+        """NaN in row 0 (the padding id) and Inf in a row reached only through
+        zero weights leave the output finite and bit-equal to the output with
+        those rows zeroed: no zero-weight slot's source row reaches the sum
+        (a kernel that added it would add 0 * inf = NaN), whether the slot
+        trails its column (not fetched) or not (fetched, then dropped)."""
+        n, f, r, k = 64, 128, 32, 8
+        rng = np.random.default_rng(7)
+        x = rng.standard_normal((n, f)).astype(np.float32)
+        idx, w = self._padded_layout(1, n, r, k, 5, min_src=2,
+                                     by_degree=by_degree)
+        pad = w == 0
+        idx[pad] = np.broadcast_to(np.arange(k) % 2, (r, k))[pad]
+        assert (idx[pad] == 0).any() and (idx[pad] == 1).any()
+        clean = x.copy()
+        clean[:2] = 0.0
+        bad = clean.copy()
+        bad[0], bad[1] = np.nan, np.inf
+        out = np.asarray(seg_aggregate(bad, idx, w, interpret=True))
+        assert np.isfinite(out).all()
+        np.testing.assert_array_equal(
+            out, np.asarray(seg_aggregate(clean, idx, w, interpret=True)))
+
     def test_unaligned_falls_back(self):
         """Unaligned widths and row counts stay on the kernel: the wrapper
         pads them (60 -> 128 lanes, 5 -> one row tile) and slices back."""
