@@ -6,8 +6,8 @@
 One process builds the cell's session once and, for each seed, puts that
 seed's weights in place and drives the three checked epochs through
 ``Session.train_epoch``, as a run does. Once the program's state is freed
-it runs the reference for each seed and prints, per seed, the three
-numbers of ``bench.check``:
+it runs the architecture's reference (``bench/models/<model>.py``) for
+each seed and prints, per seed, the three numbers of ``bench.check``:
 
 - ``program``: the program against the float32 reference (the sound
   readings; the lower end of each limit);
@@ -34,19 +34,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench import run as R  # noqa: E402
 
 
-def program_readings(setup, seeds):
+def program_readings(arch, setup, seeds):
     from bench import data
     from repro.run import RunSpec, build_session
     config, traffic = setup["config"], setup["traffic"]
     source = data.register_sources(config)
     session = build_session(RunSpec().with_overrides(
-        R.spec_overrides(config, traffic, source)))
+        R.spec_overrides(arch, config, traffic, source)))
     tr = session.trainer
     opt0, params0 = tr.opt_state, tr.params
     out = {}
     for seed in seeds:
         tr.params, tr.opt_state, tr.epoch = params0, opt0, 0
-        R.place_weights(tr, data.make_weights(config, seed))
+        R.place_weights(tr, arch.make_weights(config, seed))
         out[seed] = R.checked_steps(session)
         R.log(f"program seed {seed}: losses {out[seed]['losses']}")
     return out
@@ -62,7 +62,7 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import check, data, reference
+    from bench import check, data, models
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
@@ -71,28 +71,29 @@ def main() -> int:
     ints = lambda s: [int(v) for v in s.split(",") if v]
     seeds, cseeds = ints(args.seeds), ints(args.control_seeds)
     setup = R.load_cell(args.workload)
+    config = setup["config"]
+    arch = models.for_config(config, setup["models_dir"])
     R.require_chips(setup["cell"]["chips"])
     from repro.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
-    config = setup["config"]
-    prog = program_readings(setup, seeds)
+    prog = program_readings(arch, setup, seeds)
     gc.collect()
     graph = data.graph_for(config)
     model = config["model"]
     half = np.arange(graph.num_nodes) % 2 == 0
     for seed in sorted(set(seeds) | set(cseeds)):
-        w0 = R.host_tree(data.make_weights(config, seed))
-        ref = reference.train_steps(graph, w0, model)
+        w0 = R.host_tree(arch.make_weights(config, seed))
+        ref = arch.train_steps(graph, w0, model)
         row = {"seed": seed, "ref_losses": ref["losses"]}
         if seed in prog:
             row["program"] = check.readings(prog[seed], ref, w0)
         if seed in cseeds:
             row["control"] = check.readings(
-                reference.train_steps(graph, w0, model, dtype=jnp.bfloat16), ref, w0)
+                arch.train_steps(graph, w0, model, dtype=jnp.bfloat16), ref, w0)
             row["half"] = check.readings(
-                reference.train_steps(graph, w0, model, loss_keep=half), ref, w0)
+                arch.train_steps(graph, w0, model, loss_keep=half), ref, w0)
             row["rows"] = check.readings(
-                reference.train_steps(graph, w0, model, hook=every_16th_row_lost), ref, w0)
+                arch.train_steps(graph, w0, model, hook=every_16th_row_lost), ref, w0)
         print(json.dumps(row), flush=True)
     return 0
 

@@ -1,4 +1,6 @@
-"""The benchmark's inputs: the graph, its features and the initial weights.
+"""The benchmark's inputs: the graph and its features. The initial weights
+are each architecture's (``bench/models/<model>.py``), drawn from the run's
+seed through ``seed_words``.
 
 Everything here is made from numbers in a configuration file and a seed,
 by the benchmark and not by the program. The program receives the graph
@@ -86,46 +88,6 @@ def register_sources(config: Dict) -> str:
     return name
 
 
-def widths(config: Dict):
-    m = config["model"]
-    return ([int(m["in_dim"])] + [int(m["hidden_dim"])] * (int(m["num_layers"]) - 1)
-            + [int(m["num_classes"])])
-
-
 def seed_words(seed: int) -> int:
     """A 31-bit key for ``jax.random.PRNGKey`` from any whole seed."""
     return int(np.random.SeedSequence(seed).generate_state(1)[0] & 0x7FFFFFFF)
-
-
-def make_weights(config: Dict, seed: int):
-    """Initial parameters in the program's layout (LayerNorm scale 1 and
-    shift 0, zero bias, Glorot-uniform ``w_self`` and ``w_neigh``, and with
-    label propagation a ``[classes, in_dim]`` label embedding drawn from
-    N(0, 0.02^2)), made on the device in one jitted call, in float32 as
-    they are trained."""
-    import jax
-    import jax.numpy as jnp
-
-    dims = widths(config)
-    lp = bool(config["model"]["label_prop"])
-
-    @jax.jit
-    def make(key):
-        keys = jax.random.split(key, 2 * (len(dims) - 1) + 1)
-        layers = []
-        for l, (fi, fo) in enumerate(zip(dims[:-1], dims[1:])):
-            lim = (6.0 / (fi + fo)) ** 0.5
-            layers.append({
-                "ln_scale": jnp.ones((fi,), jnp.float32),
-                "ln_bias": jnp.zeros((fi,), jnp.float32),
-                "b": jnp.zeros((fo,), jnp.float32),
-                "w_self": jax.random.uniform(keys[2 * l], (fi, fo), jnp.float32, -lim, lim),
-                "w_neigh": jax.random.uniform(keys[2 * l + 1], (fi, fo), jnp.float32, -lim, lim),
-            })
-        params = {"layers": layers}
-        if lp:
-            params["lp_embed"] = 0.02 * jax.random.normal(
-                keys[-1], (dims[-1], dims[0]), jnp.float32)
-        return params
-
-    return make(jax.random.PRNGKey(seed_words(seed)))

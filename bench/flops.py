@@ -1,30 +1,15 @@
-"""Operations and bytes of one training epoch, from graph sizes and widths.
+"""Work counts that architectures share, and the roofline share.
 
-Counted from the graph's real nodes and edges and the published layer
+Counted from the graph's real edges and rows and the published layer
 widths, never from padded shapes: a later change that removes padding
-then reads as a gain, and this yardstick stays where it is.
+then reads as a gain, and this yardstick stays where it is. Each
+architecture's model FLOPs, and which kernel's work is which, are in its
+``bench/models/<model>.py``.
 """
 
 from __future__ import annotations
 
 from typing import Dict, Sequence
-
-
-def model_flops(nodes: int, edges: int, dims: Sequence[int]) -> float:
-    """Model FLOPs of one full-graph GraphSAGE training step.
-
-    Per layer with input width ``fi`` and output width ``fo``: the update
-    matmuls ``h @ W_self + z @ W_neigh`` are ``2 * 2 * nodes * fi * fo``
-    forward, and twice that backward (the gradients of the weights and of
-    the inputs); the mean aggregation is ``2 * edges * fi`` forward and the
-    same backward. ``edges`` counts each directed edge once, self-loops of
-    the mean aggregator included.
-    """
-    total = 0.0
-    for fi, fo in zip(dims[:-1], dims[1:]):
-        total += 3 * 4.0 * nodes * fi * fo
-        total += 2 * 2.0 * edges * fi
-    return total
 
 
 def aggregation_work(calls: Sequence[Dict], dims: Sequence[int]) -> Dict[str, float]:

@@ -1,8 +1,9 @@
 """The aggregation kernel's share of its roofline, in %: the least time
 one chip could take for its share of an epoch's aggregation work (FLOPs
 over peak FLOP/s or bytes over peak bytes/s, whichever is larger) over
-the kernel's device time per epoch. The work is counted by
-``bench.flops`` from real edges and widths, not from padded slots."""
+the kernel's device time per epoch. The work is counted by the cell's
+architecture module (``bench/models/<model>.py``) from real edges and
+widths, not from padded slots."""
 
 import sys
 
@@ -11,11 +12,10 @@ from bench import flops
 
 def read(ctx):
     t = ctx["trace"]
-    if not t or "seg_aggregate" not in t["kernel_s"]:
+    work = ctx["work"]["kernels"].get("seg_aggregate")
+    if not t or "seg_aggregate" not in t["kernel_s"] or work is None:
         return None
     seconds = t["kernel_s"]["seg_aggregate"] / ctx["trace_epochs"]
-    work = flops.aggregation_work(ctx["counts"]["kernel_calls"],
-                                  ctx["counts"]["dims"])
     p = ctx["peaks"]
     share, bound = flops.roofline_share(
         work["flops"] / ctx["chips"], work["bytes"] / ctx["chips"], seconds,

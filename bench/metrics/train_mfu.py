@@ -1,12 +1,9 @@
 """Model FLOPs of an epoch over ``epoch_s`` over the cell's chips' peak
-bfloat16 FLOP/s, in %. The FLOPs are counted by ``bench.flops`` from the
-graph's nodes and edges and the published widths."""
-
-from bench import flops
+bfloat16 FLOP/s, in %. The FLOPs are counted by the cell's architecture
+module (``bench/models/<model>.py``) from the graph's nodes and edges and
+the published widths."""
 
 
 def read(ctx):
-    c = ctx["counts"]
-    work = flops.model_flops(c["nodes"], c["edges"], c["dims"])
     peak = ctx["chips"] * ctx["peaks"]["bf16_flops_per_s"]
-    return 100.0 * work / ctx["timing"]["epoch_s"] / peak
+    return 100.0 * ctx["work"]["flops"] / ctx["timing"]["epoch_s"] / peak

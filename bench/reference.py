@@ -1,18 +1,16 @@
-"""The plain reference: GraphSAGE and Adam in straightforward ``jax.numpy``.
+"""The plain reference's shared parts: the training loop, its key
+schedule, the loss, Adam, LayerNorm and the blocked neighbour gather, in
+straightforward ``jax.numpy``. Each architecture's forward lives in
+``bench/models/<model>.py`` and runs through ``train_steps`` here.
 
 It imports nothing of the program. It trains on the whole graph at once,
 with no partition, no halo exchange, no quantization and no kernel:
-masked label propagation (a learned class embedding added to the
-features of the nodes whose labels are propagated), then per layer
-LayerNorm, dropout, the mean over each node's in-neighbours and itself,
-and ``h @ w_self + z @ w_neigh + b`` with ReLU between layers; masked
-softmax cross entropy over the training nodes whose labels were not
-propagated, and Adam (0.9, 0.999, 1e-8, no weight decay). In float32
+masked softmax cross entropy over the training nodes whose labels were
+not propagated, and Adam (0.9, 0.999, 1e-8, no weight decay). In float32
 every matmul runs at ``"highest"`` precision. The neighbour sums gather
-each node's in-neighbour rows in blocks of nodes of about the same
-in-degree, each block padded to its longest list, so that a layer's
-gathered rows never sit in memory at once and few padding rows are
-read.
+each node's neighbour rows in blocks of nodes of about the same degree,
+each block padded to its longest list, so that a layer's gathered rows
+never sit in memory at once and few padding rows are read.
 
 Randomness follows the training run's key schedule, which is part of
 what the run computes: epoch ``e`` draws from ``PRNGKey(1000003 + e)``,
@@ -39,8 +37,8 @@ NODE_BLOCK = 4096
 B1, B2, EPS = 0.9, 0.999, 1e-8
 
 
-def _neighbour_lists(rows: np.ndarray, cols: np.ndarray, n: int):
-    """Each row's columns as padded lists, for ``_gather_sum``.
+def neighbour_lists(rows: np.ndarray, cols: np.ndarray, n: int):
+    """Each row's columns as padded lists, for ``gather_sum``.
 
     Rows are sorted by length and cut into blocks of ``NODE_BLOCK``; each
     block is padded with ``n`` (a zero row) to its longest list, rounded up
@@ -72,7 +70,7 @@ def _neighbour_lists(rows: np.ndarray, cols: np.ndarray, n: int):
     return classes, jnp.asarray(place)
 
 
-def _gather_sum(x, lists):
+def gather_sum(x, lists):
     """out[r] = sum of x over row r's list, block of rows by block."""
     classes, place = lists
     xz = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
@@ -80,35 +78,6 @@ def _gather_sum(x, lists):
         jax.lax.map(lambda blk: jnp.sum(xz[blk], axis=1), cls).reshape(-1, x.shape[1])
         for cls in classes])
     return out[place]
-
-
-def neighbour_layout(src: np.ndarray, dst: np.ndarray, n: int):
-    """The arrays of the mean over in-neighbours and self: each node's
-    in-neighbour lists, its out-neighbour lists (for the backward pass)
-    and 1 / (in-degree + 1)."""
-    deg = np.bincount(dst, minlength=n).astype(np.float64) + 1.0
-    inv = jnp.asarray((1.0 / deg).astype(np.float32))[:, None]
-    return _neighbour_lists(dst, src, n), _neighbour_lists(src, dst, n), inv
-
-
-def mean_aggregate(layout):
-    """z[v] = mean of x over v's in-neighbours and v itself, with the
-    ``neighbour_layout`` arrays (passed in, not baked into the program)."""
-    fwd, bwd, inv = layout
-
-    @jax.custom_vjp
-    def agg(x):
-        return (_gather_sum(x, fwd) + x) * inv.astype(x.dtype)
-
-    def agg_fwd(x):
-        return agg(x), None
-
-    def agg_bwd(_, g):
-        gs = g * inv.astype(g.dtype)
-        return (_gather_sum(gs, bwd) + gs,)
-
-    agg.defvjp(agg_fwd, agg_bwd)
-    return agg
 
 
 def layer_norm(h, scale, bias, eps=1e-5):
@@ -130,22 +99,6 @@ def epoch_draws(epoch, n: int, widths, dropout: float, lp_rate: float):
     return sel, keeps
 
 
-def forward(params, x, labels, prop, keeps, dropout: float, agg, hook=None):
-    h = x
-    if "lp_embed" in params:
-        h = h + jnp.where(prop[:, None], params["lp_embed"][labels], 0.0)
-    layers = params["layers"]
-    for l, p in enumerate(layers):
-        h = layer_norm(h, p["ln_scale"], p["ln_bias"])
-        if keeps is not None:
-            h = jnp.where(keeps[l], h / (1.0 - dropout), 0.0)
-        z = agg(h) if hook is None else hook(agg(h))
-        h = h @ p["w_self"] + z @ p["w_neigh"] + p["b"]
-        if l < len(layers) - 1:
-            h = jax.nn.relu(h)
-    return h
-
-
 def mean_ce(logits, labels, mask):
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
@@ -157,31 +110,35 @@ def adam(params, grads, mu, nu, step, lr: float):
     """One Adam update; ``step`` counts from 1."""
     mu = jax.tree.map(lambda m, g: B1 * m + (1 - B1) * g, mu, grads)
     nu = jax.tree.map(lambda v, g: B2 * v + (1 - B2) * g * g, nu, grads)
-    c1 = (1 - B1 ** step).astype(grads["layers"][0]["b"].dtype)
-    c2 = (1 - B2 ** step).astype(grads["layers"][0]["b"].dtype)
+    dtype = jax.tree.leaves(grads)[0].dtype
+    c1 = (1 - B1 ** step).astype(dtype)
+    c2 = (1 - B2 ** step).astype(dtype)
     params = jax.tree.map(
         lambda p, m, v: p - lr * ((m / c1) / (jnp.sqrt(v / c2) + EPS)),
         params, mu, nu)
     return params, mu, nu
 
 
-def train_steps(graph, params0, model: Dict, steps: int = 3,
-                dtype=jnp.float32, loss_keep: Optional[np.ndarray] = None,
-                hook=None) -> Dict:
+def train_steps(graph, params0, model: Dict, forward, widths, layout,
+                steps: int = 3, dtype=jnp.float32,
+                loss_keep: Optional[np.ndarray] = None, hook=None) -> Dict:
     """``steps`` full-graph training steps from ``params0``, the first
     being epoch 0, with the configuration's ``model`` section (``lr``,
     ``dropout``, ``label_prop``, ``lp_rate``).
 
-    Returns the loss before each update, the first step's gradients, and
-    the parameters after the last update, all as host float32 arrays.
-    ``loss_keep`` restricts the loss to the nodes it marks and ``hook``
-    alters each layer's aggregate: the hooks by which the calibration
-    plants faults.
+    ``forward(params, x, labels, prop, keeps, dropout, layout, hook)`` is
+    the architecture's, ``widths`` its layers' input widths (one dropout
+    mask each) and ``layout`` the graph arrays its forward reads. Returns
+    the loss before each update, the first step's gradients, and the
+    parameters after the last update, all as host float32 arrays.
+    ``loss_keep`` restricts the loss to the nodes it marks and ``hook`` is
+    passed to the forward, which alters each layer's aggregate with it:
+    the hooks by which the calibration plants faults.
     """
     n = graph.num_nodes
     # Every array goes in as an argument, so that the compiled step holds
     # no graph-sized constants.
-    data = {"layout": neighbour_layout(graph.src, graph.dst, n),
+    data = {"layout": layout,
             "x": jnp.asarray(graph.x, dtype),
             "labels": jnp.asarray(graph.labels),
             "train": jnp.asarray(graph.train_mask),
@@ -190,20 +147,18 @@ def train_steps(graph, params0, model: Dict, steps: int = 3,
     dropout = float(model["dropout"])
     lp = bool(model["label_prop"])
     lr = float(model["lr"])
-    widths = [params0["layers"][l]["w_self"].shape[0]
-              for l in range(len(params0["layers"]))]
     cast = lambda t: jax.tree.map(lambda a: jnp.asarray(a, dtype), t)
 
     @jax.jit
     def step(params, mu, nu, k, data):
-        agg, labels, train = mean_aggregate(data["layout"]), data["labels"], data["train"]
+        labels, train = data["labels"], data["train"]
         sel, keeps = epoch_draws(k - 1, n, widths, dropout, float(model["lp_rate"]))
         prop = train & sel if lp else jnp.zeros_like(train)
         mask = (train & ~sel if lp else train) & data["keep_loss"]
         keeps = keeps if dropout > 0 else None
         loss, grads = jax.value_and_grad(
-            lambda p: mean_ce(forward(p, data["x"], labels, prop, keeps, dropout, agg,
-                                      hook), labels, mask))(params)
+            lambda p: mean_ce(forward(p, data["x"], labels, prop, keeps, dropout,
+                                      data["layout"], hook), labels, mask))(params)
         params, mu, nu = adam(params, grads, mu, nu, k.astype(jnp.float32), lr)
         return params, mu, nu, loss, grads
 

@@ -3,20 +3,24 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell, its configuration, its traffic mix, its limits and the readers
-of its per-layer metrics are found by name: ``BENCHMARK.json`` at the
-root of the checkout, ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json``, ``bench/limits/<cell>.json`` and
-``bench/metrics/<metric>.py``. Adding any of them needs no edit here.
+The cell, its configuration, its architecture, its traffic mix, its
+limits and the readers of its per-layer metrics are found by name:
+``BENCHMARK.json`` at the root of the checkout,
+``bench/configs/<config>.json``, ``bench/models/<model>.py`` (the
+configuration's ``model.model``: the reference, the weights, the spec keys,
+the work counts and the kernel names), ``bench/traffic/<traffic>.json``,
+``bench/limits/<cell>.json`` and ``bench/metrics/<metric>.py``. Adding any
+of them needs no edit here.
 
 A run builds the session through the program's ``build_session`` from the
 benchmark's own graph, puts the weights drawn from ``--seed`` in place,
 and trains three epochs through ``Session.train_epoch``: they compile
 (or load from the persistent cache) and warm up, and their losses, the
 first gradient and the parameters after them are what ``correct``
-compares with the plain reference. Then it trains for ``--seconds``
-(the window), and with ``--trace 1`` traces a few more epochs. After the
-program's state is freed the reference follows the same three steps.
+compares with the architecture's plain reference. Then it trains for
+``--seconds`` (the window), and with ``--trace 1`` traces a few more
+epochs. After the program's state is freed the reference follows the
+same three steps.
 
 The last line of standard output is one JSON object. A run that finds no
 TPU, or fewer chips than the cell asks for, exits 2 and prints none.
@@ -46,7 +50,6 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-KERNEL = "seg_aggregate"
 CHECK_STEPS = 3
 
 
@@ -83,23 +86,22 @@ def load_cell(name: str, root: str = ROOT) -> Dict:
         "end_to_end": bench["end_to_end"],
         "per_layer": bench["per_layer"],
         "metrics_dir": os.path.join(here, "metrics"),
+        "models_dir": os.path.join(here, "models"),
         "peaks": _json(os.path.join(here, "peaks.json")),
     }
 
 
-def spec_overrides(config: Dict, traffic: Dict, source: str):
+def spec_overrides(arch, config: Dict, traffic: Dict, source: str):
+    """The program's spec keys: the graph's, the architecture module's
+    ``spec(config)``, then the traffic mix's."""
     m, g = config["model"], config["graph"]
     over = {
         "graph.source": source, "graph.features": source,
         "graph.nodes": g["nodes"], "graph.classes": m["num_classes"],
         "graph.avg_degree": g["avg_degree"], "graph.feat_dim": m["in_dim"],
-        "graph.norm": "mean", "graph.seed": g["seed"],
-        "model.model": m["model"], "model.hidden_dim": m["hidden_dim"],
-        "model.num_layers": m["num_layers"], "model.dropout": m["dropout"],
-        "model.norm": m["norm"], "model.label_prop": m["label_prop"],
-        "model.lp_rate": m["lp_rate"],
-        "exec.lr": m["lr"], "exec.seed": 0,
+        "graph.seed": g["seed"], "exec.lr": m["lr"], "exec.seed": 0,
     }
+    over.update(arch.spec(config))
     over.update(traffic["spec"])
     return [f"{k}={str(v).lower() if isinstance(v, bool) else v}"
             for k, v in over.items()]
@@ -216,11 +218,12 @@ def run(setup: Dict, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
 
-    from bench import check, data, reference
+    from bench import check, data, models
     from repro.run import RunSpec, build_session
     from repro.utils.compile_cache import enable_compile_cache
 
     cell, config, traffic = setup["cell"], setup["config"], setup["traffic"]
+    arch = models.for_config(config, setup["models_dir"])
     devs = require_chips(cell["chips"]) if require_tpu else jax.devices()
     # Cache every program, the eager optimizer's small ones too, so that a
     # run after the first in a checkout compiles nothing.
@@ -233,14 +236,14 @@ def run(setup: Dict, seed: int, seconds: float, trace: bool,
         if ev == "/jax/core/compile/backend_compile_duration" else None)
 
     source = data.register_sources(config)
-    spec = RunSpec().with_overrides(spec_overrides(config, traffic, source))
+    spec = RunSpec().with_overrides(spec_overrides(arch, config, traffic, source))
     t = time.perf_counter()
     session = build_session(spec)
     host_build_s = time.perf_counter() - t
     log(f"host build {host_build_s:.3f} s: {session.graph.num_nodes} nodes, "
         f"{session.graph.num_edges} edges (self-loops included); {spec.describe()}")
 
-    weights = data.make_weights(config, seed)
+    weights = arch.make_weights(config, seed)
     weights0 = host_tree(weights)
     place_weights(session.trainer, weights)
     del weights
@@ -270,7 +273,7 @@ def run(setup: Dict, seed: int, seconds: float, trace: bool,
         tmp = tempfile.mkdtemp(prefix="bench-trace-")
         try:
             path = record_trace(session, int(traffic["trace_epochs"]), tmp)
-            reduced = trace_reduce.reduce(path, kernels=(KERNEL,))
+            reduced = trace_reduce.reduce(path, kernels=arch.KERNELS)
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
         log(f"trace: {json.dumps(reduced)}")
@@ -280,22 +283,24 @@ def run(setup: Dict, seed: int, seconds: float, trace: bool,
     counts = {
         "nodes": graph.num_nodes,
         "edges": graph.src.size + graph.num_nodes,
-        "dims": data.widths(config),
+        "dims": arch.widths(config),
         "kernel_calls": kernel_calls(session),
     }
     del session
     gc.collect()
 
     t = time.perf_counter()
-    ref = reference.train_steps(graph, weights0, config["model"], CHECK_STEPS)
+    ref = arch.train_steps(graph, weights0, config["model"], CHECK_STEPS)
     values = check.readings(prog, ref, weights0)
     log(f"reference {time.perf_counter() - t:.3f} s, losses {ref['losses']}")
     limits = setup["limits"]
     correct = limits is not None and check.judge(values, limits)
     failed = sum(not math.isfinite(v) for v in losses)
+    work = models.epoch_work(arch, counts)
+    log(f"work per epoch: {json.dumps(work)}")
 
     ctx = {"config": config, "traffic": traffic, "cell": cell,
-           "chips": cell["chips"], "counts": counts, "trace": reduced,
+           "chips": cell["chips"], "counts": counts, "work": work, "trace": reduced,
            "trace_epochs": traffic.get("trace_epochs"),
            "peaks": _peaks(setup["peaks"], devs[0].device_kind),
            "timing": {"host_build_s": host_build_s, "warmup_s": warmup_s,

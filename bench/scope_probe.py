@@ -33,8 +33,8 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
 
 from bench import run as R  # noqa: E402
 
-PROGRAM_METRICS = ("agg_slot_fill", "agg_ns_per_slot", "compile_s",
-                   "build_partition_s")
+READERS = ("agg_slot_fill", "agg_ns_per_slot", "compile_s",
+                   "build_partition_s", "agg_kernel_ms")
 
 
 def span_cost_s(reps: int = 20000) -> float:
@@ -50,21 +50,22 @@ def span_cost_s(reps: int = 20000) -> float:
 def probe(setup, seed: int, epochs: int) -> dict:
     import jax
 
-    from bench import data, scopes, trace_reduce
+    from bench import data, models, scopes, trace_reduce
     from repro.run import RunSpec, build_session
     from repro.utils import trace
     from repro.utils.compile_cache import enable_compile_cache
 
     cell, config, traffic = setup["cell"], setup["config"], setup["traffic"]
+    arch = models.for_config(config, setup["models_dir"])
     devs = R.require_chips(cell["chips"])
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     enable_compile_cache()
     source = data.register_sources(config)
-    spec = RunSpec().with_overrides(R.spec_overrides(config, traffic, source))
+    spec = RunSpec().with_overrides(R.spec_overrides(arch, config, traffic, source))
     t = time.perf_counter()
     session = build_session(spec)
     host_build_s = time.perf_counter() - t
-    R.place_weights(session.trainer, data.make_weights(config, seed))
+    R.place_weights(session.trainer, arch.make_weights(config, seed))
     for _ in range(R.CHECK_STEPS + int(traffic.get("warmup_epochs", 0))):
         session.train_epoch()
     untraced = []
@@ -80,7 +81,7 @@ def probe(setup, seed: int, epochs: int) -> dict:
         path = R.record_trace(session, epochs, tmp)
         traced_s = (time.perf_counter() - t) / epochs
         spans = trace.delta(before, trace.snapshot())["spans"]
-        reduced = trace_reduce.reduce(path, kernels=(R.KERNEL,))
+        reduced = trace_reduce.reduce(path, kernels=arch.KERNELS)
         peak = max(R.device_peak_bytes(d) for d in devs[:cell["chips"]])
         op_map = session.op_scopes()
         st = scopes.scope_time(path, op_map["module"], op_map["ops"])
@@ -89,12 +90,11 @@ def probe(setup, seed: int, epochs: int) -> dict:
         shutil.rmtree(tmp, ignore_errors=True)
 
     ctx = {"trace": reduced, "trace_epochs": epochs}
-    metrics = {m: R.read_metric(setup["metrics_dir"], m, ctx) for m in PROGRAM_METRICS}
+    metrics = {m: R.read_metric(setup["metrics_dir"], m, ctx) for m in READERS}
     per = lambda s: 1e3 * s / epochs  # noqa: E731
     metrics.update({
         "agg_layer_ms": per(scopes.scope_sum(st["by_scope"], "aggregate")),
         "update_ms": per(scopes.scope_sum(st["by_scope"], "update")),
-        "agg_kernel_ms": per(reduced["kernel_s"].get(R.KERNEL, 0.0)),
     })
     cost = span_cost_s()
     top = sorted(st["by_scope"].items(), key=lambda kv: -kv[1])

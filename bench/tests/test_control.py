@@ -8,12 +8,13 @@ import jax.numpy as jnp
 
 
 def test_bfloat16_control_is_not_correct(tiny_setup):
-    from bench import check, data, reference, run as R
+    from bench import check, data, models, run as R
     setup = tiny_setup(2048)
     config = setup["config"]
+    arch = models.for_config(config, setup["models_dir"])
     graph = data.graph_for(config)
-    w0 = R.host_tree(data.make_weights(config, 2**31 + 11))
-    ref = reference.train_steps(graph, w0, config["model"])
-    control = reference.train_steps(graph, w0, config["model"], dtype=jnp.bfloat16)
+    w0 = R.host_tree(arch.make_weights(config, 2**31 + 11))
+    ref = arch.train_steps(graph, w0, config["model"])
+    control = arch.train_steps(graph, w0, config["model"], dtype=jnp.bfloat16)
     values = check.readings(control, ref, w0)
     assert not check.judge(values, setup["limits"]), values
