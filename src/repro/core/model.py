@@ -2,7 +2,9 @@
 
 The forward is parameterized by ``agg_fn(layer, h) -> z`` so the identical
 model runs on a single device (full-graph ELL aggregation) or distributed
-(local aggregation + pre/post halo exchange). Quantization and masked label
+(local aggregation + pre/post halo exchange). GAT's aggregation is weighted
+by attention computed from the layer's parameters, so a GAT layer calls
+``agg_fn(layer, W x, scores=(e_src, e_dst))`` (``layers.gat_layer``). Quantization and masked label
 propagation (§6.1) are part of the model flow:
 
   (1) masked LP: random subset of train labels embedded into the features,
@@ -13,6 +15,7 @@ propagation (§6.1) are part of the model flow:
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
@@ -34,7 +37,8 @@ class GCNConfig:
     label_prop: bool = True      # masked label propagation (§6.1)
     lp_rate: float = 0.5         # fraction of train labels propagated
     quant_bits: int = 0          # 0 = fp32 comm; 2 = paper's Int2 scheme
-    gat_heads: int = 4
+    gat_heads: int = 4           # GAT hidden layers: heads, concatenated
+    gat_out_heads: int = 1       # GAT output layer: heads, averaged
 
     def dims(self) -> List[int]:
         return [self.in_dim] + [self.hidden_dim] * (self.num_layers - 1) + [self.num_classes]
@@ -43,9 +47,12 @@ class GCNConfig:
 def init_params(key: jax.Array, cfg: GCNConfig) -> Dict:
     ks = jax.random.split(key, cfg.num_layers + 1)
     dims = cfg.dims()
+    last = cfg.num_layers - 1
     params: Dict = {
         "layers": [
-            L.init_layer(ks[i], cfg.model, dims[i], dims[i + 1], cfg.gat_heads)
+            L.init_layer(ks[i], cfg.model, dims[i], dims[i + 1],
+                         cfg.gat_out_heads if i == last else cfg.gat_heads,
+                         concat=i < last, norm=cfg.norm == "layer")
             for i in range(cfg.num_layers)
         ]
     }
@@ -75,7 +82,7 @@ def forward(
     x: jax.Array,                    # [N, in_dim] node features
     labels: jax.Array,               # [N] int labels
     prop_mask: jax.Array,            # [N] bool: labels embedded into features
-    agg_fn: Callable[[int, jax.Array], jax.Array],
+    agg_fn: Callable[..., jax.Array],
     *,
     train: bool = False,
     dropout_key: Optional[jax.Array] = None,
@@ -95,11 +102,16 @@ def forward(
                     dropout_key, sub = jax.random.split(dropout_key)
                     keep = jax.random.bernoulli(sub, 1.0 - cfg.dropout, h.shape)
                     h = jnp.where(keep, h / (1.0 - cfg.dropout), 0.0)
+            if cfg.model == "gat":
+                # Attention reads the layer's parameters: the layer calls
+                # agg_fn(l, W x, scores=(e_src, e_dst)) itself.
+                h = L.gat_layer(p, h, functools.partial(agg_fn, l),
+                                last=l == cfg.num_layers - 1)
+                continue
             with jax.named_scope("aggregate"):
                 z = agg_fn(l, h)
             with jax.named_scope("update"):
-                # GAT fuses aggregate+update (attention needs both ends)
-                h = z if cfg.model == "gat" else L.apply_update(cfg.model, p, h, z)
+                h = L.apply_update(cfg.model, p, h, z)
                 if l < cfg.num_layers - 1:
                     h = jax.nn.relu(h)
     return h

@@ -37,7 +37,7 @@ from repro.core.halo import (
     stack_halo_plan,
     stack_hier_plan,
 )
-from repro.core.layers import gat_aggregate, gat_aggregate_bucketed
+from repro.core.layers import gat_aggregate, gat_attention
 from repro.graph.remote import (
     HierPartitionedGraph,
     build_halo_plan,
@@ -47,6 +47,7 @@ from repro.graph.structure import (
     Graph,
     bucketed_ell_from_csr,
     ell_from_csr,
+    reverse_slot_index,
     stack_bucketed_ells,
     transpose_csr,
 )
@@ -76,6 +77,9 @@ class SingleGraphData(NamedTuple):
     # layout is built once at preprocessing time.
     ell: Optional[DeviceBucketedEll] = None
     ell_t: Optional[DeviceBucketedEll] = None
+    # Each reverse slot's forward slot (graph.structure.reverse_slot_index):
+    # GAT's attention weights reach the transposed pass through it.
+    ell_t_slot: Optional[Tuple[jax.Array, ...]] = None
 
 
 def prepare_single(g: Graph, x: np.ndarray, eval_mask: Optional[np.ndarray] = None,
@@ -98,13 +102,14 @@ def prepare_single(g: Graph, x: np.ndarray, eval_mask: Optional[np.ndarray] = No
         idx = np.zeros((g.num_nodes, 1), np.int32)
         w = np.zeros((g.num_nodes, 1), np.float32)
         valid = np.zeros((g.num_nodes, 1), bool)
-    ell = ell_t = None
+    ell = ell_t = slot_t = None
     if "bucketed" in layouts:
-        ell = device_bucketed(
-            stack_bucketed_ells([bucketed_ell_from_csr(csr)]), squeeze=True)
-        ell_t = device_bucketed(
-            stack_bucketed_ells([bucketed_ell_from_csr(transpose_csr(csr))]),
-            squeeze=True)
+        stacked = stack_bucketed_ells([bucketed_ell_from_csr(csr)])
+        stacked_t = stack_bucketed_ells([bucketed_ell_from_csr(transpose_csr(csr))])
+        ell = device_bucketed(stacked, squeeze=True)
+        ell_t = device_bucketed(stacked_t, squeeze=True)
+        slot_t = tuple(jnp.asarray(a[0])
+                       for a in reverse_slot_index(stacked, stacked_t))
     return SingleGraphData(
         x=jnp.asarray(x),
         labels=jnp.asarray(g.labels, jnp.int32),
@@ -115,18 +120,17 @@ def prepare_single(g: Graph, x: np.ndarray, eval_mask: Optional[np.ndarray] = No
         ell_valid=jnp.asarray(valid),
         ell=ell,
         ell_t=ell_t,
+        ell_t_slot=slot_t,
     )
 
 
-def make_single_agg_fn(cfg: M.GCNConfig, data: SingleGraphData, params_getter,
-                       use_kernel: bool = False):
-    def agg_fn(l: int, h: jax.Array) -> jax.Array:
-        if cfg.model == "gat":
-            p = params_getter()["layers"][l]
+def make_single_agg_fn(data: SingleGraphData, use_kernel: bool = False):
+    def agg_fn(l: int, h: jax.Array, scores=None) -> jax.Array:
+        if scores is not None:  # GAT: h is W x, weighted by attention
             if data.ell is not None:
-                return gat_aggregate_bucketed(p, h, data.ell, h.shape[0],
-                                              cfg.gat_heads)
-            return gat_aggregate(p, h, data.ell_idx, data.ell_valid, cfg.gat_heads)
+                return gat_attention(h, *scores, data.ell, data.ell_t,
+                                     data.ell_t_slot)
+            return gat_aggregate(h, *scores, data.ell_idx, data.ell_valid)
         if use_kernel:
             return kernel_aggregate(h, data.ell_idx, data.ell_w)
         return seg_aggregate_ref(h, data.ell_idx, data.ell_w)
@@ -143,7 +147,7 @@ def single_train_step(params, opt_state, cfg: M.GCNConfig, data: SingleGraphData
         loss_mask = data.train_mask
 
     def loss_fn(p):
-        agg = make_single_agg_fn(cfg, data, lambda: p)
+        agg = make_single_agg_fn(data)
         logits = M.forward(p, cfg, data.x, data.labels, prop_mask, agg,
                            train=True, dropout_key=kd)
         ls, correct, cnt = M.loss_and_metrics(logits, data.labels, loss_mask)
@@ -158,7 +162,7 @@ def single_train_step(params, opt_state, cfg: M.GCNConfig, data: SingleGraphData
 def single_eval(params, cfg: M.GCNConfig, data: SingleGraphData):
     # Inference-time LP: propagate all train labels, score on eval nodes.
     prop = data.train_mask if cfg.label_prop else jnp.zeros_like(data.train_mask)
-    agg = make_single_agg_fn(cfg, data, lambda: params)
+    agg = make_single_agg_fn(data)
     logits = M.forward(params, cfg, data.x, data.labels, prop, agg, train=False)
     _, correct, cnt = M.loss_and_metrics(logits, data.labels, data.eval_mask)
     return correct / jnp.maximum(cnt, 1.0)
@@ -215,6 +219,9 @@ class WorkerData(NamedTuple):
     # fallback.
     ell: Optional[DeviceBucketedEll] = None
     ell_t: Optional[DeviceBucketedEll] = None
+    # GAT only: each reverse slot's forward slot, one [.., Rb, K] int32 per
+    # reverse bucket (graph.structure.reverse_slot_index).
+    ell_t_slot: Optional[Tuple[jax.Array, ...]] = None
 
 
 @dataclass(frozen=True)
@@ -341,6 +348,7 @@ class HostWorkerData(NamedTuple):
     plan: Optional[object]   # graph.remote.HaloPlan (flat) or None
     hier_plan: Optional[object]  # graph.remote.HierHaloPlan or None
     max_owned: int
+    ell_t_slot: Optional[list] = None  # reverse_slot_index output (GAT)
 
 
 def prepare_distributed_host(
@@ -348,9 +356,12 @@ def prepare_distributed_host(
     x: np.ndarray,
     pg,
     eval_mask: Optional[np.ndarray] = None,
+    attention: bool = False,
 ) -> HostWorkerData:
     """Pad per-partition arrays to common shapes and stack on the worker
     axis — the host (numpy-only) half of :func:`prepare_distributed`.
+    ``attention`` also builds each reverse slot's forward slot, which an
+    aggregation whose weights the layer computes (GAT) needs.
 
     ``g`` must already carry edge weights (use gcn_normalized/mean_normalized
     *before* partitioning so pre-aggregation applies source-side weights).
@@ -397,12 +408,15 @@ def prepare_distributed_host(
     local_ell_t = base.local_ell_t or [
         bucketed_ell_from_csr(transpose_csr(c)) for c in pg.local_csr]
 
+    ell_stacked = stack_bucketed_ells(local_ell)
+    ell_t_stacked = stack_bucketed_ells(local_ell_t)
     common = dict(
         x=xs, labels=ls, train_mask=tm, eval_mask=em, owned_mask=om,
         coo_src=cs, coo_dst=cd_, coo_w=cw,
-        ell_stacked=stack_bucketed_ells(local_ell),
-        ell_t_stacked=stack_bucketed_ells(local_ell_t),
+        ell_stacked=ell_stacked, ell_t_stacked=ell_t_stacked,
         max_owned=M_,
+        ell_t_slot=(reverse_slot_index(ell_stacked, ell_t_stacked)
+                    if attention else None),
     )
     if isinstance(pg, HierPartitionedGraph):
         # build_hier_halo_plan already pads both levels to quant row groups.
@@ -428,6 +442,8 @@ def _lift_worker_data(hwd: HostWorkerData) -> WorkerData:
         coo_w=jnp.asarray(hwd.coo_w),
         ell=device_bucketed(hwd.ell_stacked),
         ell_t=device_bucketed(hwd.ell_t_stacked),
+        ell_t_slot=(None if hwd.ell_t_slot is None else
+                    tuple(jnp.asarray(a, jnp.int32) for a in hwd.ell_t_slot)),
     )
     if hwd.hier_plan is not None:
         return WorkerData(**common, hier_plan=stack_hier_plan(
@@ -485,7 +501,12 @@ def _dist_forward(params, cfg: M.GCNConfig, dc: DistConfig, wd: WorkerData,
     new_cache: List = []
 
     def agg_fn_factory(dropout_key):
-        def agg_fn(l: int, h: jax.Array) -> jax.Array:
+        def agg_fn(l: int, h: jax.Array, scores=None) -> jax.Array:
+            if scores is not None:
+                # GAT: attention over the local graph. One worker holds
+                # every edge (the trainer refuses more), so no halo moves.
+                return gat_attention(h, *scores, wd.ell, wd.ell_t,
+                                     wd.ell_t_slot)
             kq = jax.random.fold_in(key, 7919 + l) if key is not None else None
             entry = halo_cache[l] if halo_cache is not None else None
             with jax.named_scope("exchange_issue"):
@@ -588,6 +609,13 @@ class DistributedTrainer:
                 "agg_backend='ell' needs the bucketed layout in WorkerData "
                 "(wd.ell is None — build it via prepare_distributed, or "
                 "fall back to agg_backend='coo')")
+        if cfg.model == "gat" and (dc.nparts != 1 or dc.hierarchical
+                                   or mode != "shard_map"
+                                   or wd.ell_t_slot is None):
+            raise ValueError(
+                "GAT trains as one worker under shard_map, with the reverse "
+                "slot index in WorkerData (prepare_distributed_host(..., "
+                "attention=True)); see RunSpec.validate_training")
         worker_step = make_dist_train_step(cfg, dc, use_cache=self.use_cache)
         worker_eval = make_dist_eval(cfg, dc)
         # (params, wd, key[, cache, epoch]): workers map their leading axis
@@ -686,7 +714,9 @@ class DistributedTrainer:
         """Real edges (non-zero weights) and kernel slots (padding included)
         of one epoch's bucketed aggregations: the local graph and every
         receive scatter that has edges, each forward and backward in every
-        layer. Empty when the step aggregates no bucketed layout."""
+        layer; for GAT also ``sddmm.*``, the SDDMM pass over the forward
+        layout in every layer. Empty when the step aggregates no bucketed
+        layout."""
         if self.dc.agg_backend != "ell":
             return {}
         wd = self.wd
@@ -695,12 +725,21 @@ class DistributedTrainer:
         layouts = [wd.ell, wd.ell_t] + [
             lay for p in plans if p.recv_ell is not None
             for lay in (p.recv_ell, p.recv_ell_t)]
+        if self.cfg.model == "gat":
+            # Attention runs on the local graph only: the weighted kernel
+            # over both layouts, and the SDDMM pass over the forward one.
+            layouts = [wd.ell, wd.ell_t]
         workers = int(np.prod(wd.x.shape[:-2]))
-        slots = sum(bucketed_slots(lay, workers, fold=self.mode == "vmap")
-                    for lay in layouts)
+        fold = self.mode == "vmap"
+        slots = sum(bucketed_slots(lay, workers, fold) for lay in layouts)
         edges = int(_count_nonzero([b.w for lay in layouts for b in lay.buckets]))
         layers = self.cfg.num_layers
-        return {"agg.edges": layers * edges, "agg.slots": layers * slots}
+        counts = {"agg.edges": layers * edges, "agg.slots": layers * slots}
+        if self.cfg.model == "gat":
+            counts["sddmm.edges"] = layers * int(
+                _count_nonzero([b.w for b in wd.ell.buckets]))
+            counts["sddmm.slots"] = layers * bucketed_slots(wd.ell, workers, fold)
+        return counts
 
     def _unreplicate(self, tree):
         if self.mode == "vmap":

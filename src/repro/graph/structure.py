@@ -387,3 +387,47 @@ def stack_bucketed_ells(
             w[p, :n] = b.w
         out.append((k, rows, idx, w))
     return out
+
+
+def reverse_slot_index(
+    stacked: List[Tuple[int, np.ndarray, np.ndarray, np.ndarray]],
+    stacked_t: List[Tuple[int, np.ndarray, np.ndarray, np.ndarray]],
+) -> List[np.ndarray]:
+    """For every slot of the reverse-graph layout, the slot of the forward
+    layout that holds the same edge.
+
+    ``stacked`` and ``stacked_t`` are ``stack_bucketed_ells`` outputs of
+    the same worker graphs and of their transposes. Each worker's forward
+    slots are numbered bucket after bucket, row-major within a bucket's
+    ``[R, K]`` (the order of ``concatenate([w.reshape(-1) for each
+    bucket])``), 0..S-1. Returns one ``[P, R, K]`` int32 array per reverse
+    bucket: the forward number of each real slot, and S on padding. Slot
+    weights computed in the forward order (attention) then reach the
+    transposed aggregation through one gather, with a zero appended at S.
+    Real slots are those of nonzero weight; an edge listed twice maps both
+    reverse slots to one of its forward slots, whose weight is the same.
+    """
+    workers = stacked[0][1].shape[0] if stacked else 0
+    total = sum(rows.shape[1] * k for k, rows, _, _ in stacked)
+    n = 1 + max((int(a.max()) for _, rows, idx, _ in stacked + stacked_t
+                 for a in (rows, idx) if a.size), default=0)
+    out = [np.full(idx.shape, total, np.int32) for _, _, idx, _ in stacked_t]
+    for p in range(workers):
+        keys, slots, off = [], [], 0
+        for k, rows, idx, w in stacked:
+            r, s = np.nonzero(w[p])
+            keys.append(rows[p, r].astype(np.int64) * n + idx[p, r, s])
+            slots.append(off + r * k + s)
+            off += rows.shape[1] * k
+        keys, slots = np.concatenate(keys), np.concatenate(slots)
+        order = np.argsort(keys, kind="stable")
+        keys, slots = keys[order], slots[order]
+        for j, (k, rows, idx, w) in enumerate(stacked_t):
+            r, s = np.nonzero(w[p])
+            want = idx[p, r, s].astype(np.int64) * n + rows[p, r]
+            at = np.minimum(np.searchsorted(keys, want), max(keys.size - 1, 0))
+            if want.size and not np.array_equal(keys[at], want):
+                raise ValueError("reverse_slot_index: the reverse layout holds "
+                                 "an edge the forward layout does not")
+            out[j][p, r, s] = slots[at]
+    return out

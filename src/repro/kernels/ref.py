@@ -15,11 +15,35 @@ import jax.numpy as jnp
 def seg_aggregate_ref(
     x: jax.Array,      # [N, F] source features
     ell_idx: jax.Array,  # [R, K] int32 source ids per dst-row slot
-    ell_w: jax.Array,    # [R, K] f32 edge weights (0 = padding)
+    ell_w: jax.Array,    # [R, K] f32 edge weights (0 = padding), or [R, K, H]
 ) -> jax.Array:
-    """out[r] = sum_k ell_w[r, k] * x[ell_idx[r, k]] — the paper's index_add/SpMM."""
+    """out[r] = sum_k ell_w[r, k] * x[ell_idx[r, k]] — the paper's index_add/SpMM.
+
+    With ``[R, K, H]`` weights, F holds H equal column groups (heads) and
+    head h of row r is weighted by ``ell_w[r, k, h]``."""
     gathered = x[ell_idx]                      # [R, K, F]
-    return jnp.einsum("rk,rkf->rf", ell_w.astype(x.dtype), gathered)
+    if ell_w.ndim == 2:
+        return jnp.einsum("rk,rkf->rf", ell_w.astype(x.dtype), gathered)
+    r, k, heads = ell_w.shape
+    out = jnp.einsum("rkh,rkhd->rhd", ell_w.astype(x.dtype),
+                     gathered.reshape(r, k, heads, -1))
+    return out.reshape(r, x.shape[1])
+
+
+def seg_sddmm_ref(
+    g: jax.Array,        # [R, F] destination rows
+    x: jax.Array,        # [N, F] source rows
+    ell_idx: jax.Array,  # [R, K] int32
+    ell_w: jax.Array,    # [R, K] f32 (0 = padding)
+    heads: int,
+) -> jax.Array:
+    """out[r, k, h] = <g[r, head h], x[ell_idx[r, k], head h]>, 0 on
+    padding slots: the SDDMM behind an attention-weighted aggregation's
+    gradient with respect to its slot weights."""
+    r, k = ell_idx.shape
+    gathered = x[ell_idx].reshape(r, k, heads, -1)
+    dots = jnp.einsum("rhd,rkhd->rkh", g.reshape(r, heads, -1), gathered)
+    return jnp.where((ell_w != 0)[..., None], dots, 0.0)
 
 
 def quant_pack_ref(

@@ -69,6 +69,20 @@ forward gather into the scatter-add access pattern the paper's operator
 exists to avoid. The cotangent of the layout arrays is structurally zero
 (edge weights are preprocessing constants).
 
+Attention-weighted form (GAT)
+-----------------------------
+
+With ``[R, K, H]`` weights the row's F columns are H equal heads, each
+padded by the wrapper to whole lanes, and head h of a slot's row is
+weighted by its own ``w[r, k, h]``: one row copy per slot serves every
+head (``bucketed_weighted_sum``). Its gradient with respect to the
+weights is the per-slot, per-head dot product of the destination row's
+cotangent with the slot's source row, a sampled dense-dense product
+(SDDMM): ``seg_sddmm``, a second ``pallas_call`` with the same fetch loop
+and launch shape, named apart so that a trace tells the two kernels apart
+(``bucketed_sddmm``). The attention op that drives both, with its own
+custom VJP, is ``core.layers.gat_attention``.
+
 Realization policy (``use_kernel``): ``"auto"`` runs the compiled kernel on
 a TPU and the XLA reference (``kernels.ref.seg_aggregate_ref``) elsewhere;
 ``True`` forces the kernel (interpreted off-TPU, for tests); ``False``
@@ -108,24 +122,20 @@ def _block_k(k: int) -> int:
     return min(MAX_BLOCK_K, 1 << max(k - 1, 0).bit_length())
 
 
-def _seg_aggregate_kernel(idx_ref, w_ref, x_hbm, out_ref, buf, sem, *,
-                          block_rows: int, block_k: int):
-    """One (BR, F) destination tile, one chunk of KC neighbour slots.
+def _live_rows(w, heads: int = 1):
+    """Per slot column, the row after its last one of nonzero weight in
+    any head: ``[1, KC]``. The rows from there on are not fetched."""
+    row1 = jax.lax.broadcasted_iota(jnp.int32, w.shape[-2:], 0) + 1
+    nonzero = (w != 0 if heads == 1
+               else jnp.max(jnp.where(w != 0, 1, 0), axis=0) > 0)
+    return jnp.max(jnp.where(nonzero, row1, 0), axis=0, keepdims=True)
 
-    ``idx_ref`` holds the chunk's BR*KC source ids slot-major (slot k of
-    row r at ``k * BR + r``); ``out_ref`` stays resident across chunks and
-    accumulates in float32, slot by slot.
-    """
-    @pl.when(pl.program_id(1) == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
 
-    w = w_ref[...]
-    # Per slot column, the row after its last one of nonzero weight: the
-    # rows from there on are not fetched.
-    row1 = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0) + 1
-    live = jnp.max(jnp.where(w != 0, row1, 0), axis=0, keepdims=True)
-
+def _fetch_rows(idx_ref, x_hbm, buf, sem, live, *, block_rows: int,
+                block_k: int):
+    """Copy the step's source rows into ``buf`` (slot k's rows into
+    ``buf[k]``), each slot column up to its ``live`` row, and wait for
+    them all."""
     def fetch_column(k):
         """Start slot k's row copies up to its column's last nonzero weight."""
         col = k * block_rows
@@ -151,19 +161,75 @@ def _seg_aggregate_kernel(idx_ref, w_ref, x_hbm, out_ref, buf, sem, *,
     started = sum(fetch_column(k) for k in range(block_k))
     jax.lax.fori_loop(0, started // WAIT_ROWS, wait(WAIT_ROWS), 0)
     jax.lax.fori_loop(0, started % WAIT_ROWS, wait(1), 0)
-    acc = out_ref[...]
-    for k in range(block_k):
-        # A slot that was not fetched holds stale VMEM: select, not 0 * row.
-        wk = w[:, k:k + 1]
-        acc = acc + jnp.where(wk != 0, wk * buf[k].reshape(block_rows, -1), 0.0)
-    out_ref[...] = acc
 
 
-def _vmem_limit(block_rows: int, fp: int) -> int:
-    """Scoped-VMEM request: the gathered rows plus double-buffered output
-    and (lane-padded) weight blocks, with headroom for Mosaic's own
-    temporaries."""
-    need = 4 * (SLOTS_PER_STEP * fp + 2 * block_rows * (fp + LANES))
+def _seg_aggregate_kernel(idx_ref, w_ref, x_hbm, out_ref, buf, sem, *,
+                          block_rows: int, block_k: int, heads: int):
+    """One (BR, F) destination tile, one chunk of KC neighbour slots.
+
+    ``idx_ref`` holds the chunk's BR*KC source ids slot-major (slot k of
+    row r at ``k * BR + r``); ``out_ref`` stays resident across chunks and
+    accumulates in float32, slot by slot. With one head ``w_ref`` is the
+    ``[BR, KC]`` slot weights; with H heads it is ``[H, BR, KC]`` and head
+    h weighs the h-th of the row's H equal, lane-aligned column groups.
+    """
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    w = w_ref[...]
+    _fetch_rows(idx_ref, x_hbm, buf, sem, _live_rows(w, heads),
+                block_rows=block_rows, block_k=block_k)
+    if heads == 1:
+        acc = out_ref[...]
+        for k in range(block_k):
+            # A slot that was not fetched holds stale VMEM: select, not 0 * row.
+            wk = w[:, k:k + 1]
+            acc = acc + jnp.where(wk != 0, wk * buf[k].reshape(block_rows, -1), 0.0)
+        out_ref[...] = acc
+        return
+    fh = out_ref.shape[-1] // heads
+    for h in range(heads):
+        cols = slice(h * fh, (h + 1) * fh)
+        acc = out_ref[:, cols]
+        for k in range(block_k):
+            wk = w[h, :, k:k + 1]
+            row = buf[k, :, :, cols].reshape(block_rows, fh)
+            acc = acc + jnp.where(wk != 0, wk * row, 0.0)
+        out_ref[:, cols] = acc
+
+
+def _seg_sddmm_kernel(idx_ref, w_ref, x_hbm, g_ref, out_ref, buf, sem, *,
+                      block_rows: int, block_k: int, heads: int):
+    """Per-slot, per-head dot products of one (BR, KC) block of slots:
+    ``out[h, r, k] = <g[r, head h], x[idx[r, k], head h]>``, 0 where the
+    layout's weight ``w_ref`` is 0 (padding). The source rows are fetched
+    as the aggregation fetches them; ``g_ref`` is the block's (BR, F)
+    destination rows."""
+    w = w_ref[...]
+    _fetch_rows(idx_ref, x_hbm, buf, sem, _live_rows(w),
+                block_rows=block_rows, block_k=block_k)
+    fh = g_ref.shape[-1] // heads
+    col = jax.lax.broadcasted_iota(jnp.int32, (block_rows, block_k), 1)
+    for h in range(heads):
+        cols = slice(h * fh, (h + 1) * fh)
+        gh = g_ref[:, cols]
+        acc = jnp.zeros((block_rows, block_k), jnp.float32)
+        for k in range(block_k):
+            row = buf[k, :, :, cols].reshape(block_rows, fh)
+            acc = jnp.where(col == k, jnp.sum(gh * row, axis=1, keepdims=True), acc)
+        # A slot that was not fetched holds stale VMEM: select, not 0 * dot.
+        out_ref[h] = jnp.where(w != 0, acc, 0.0)
+
+
+def _vmem_limit(block_rows: int, fp: int, row_words: Optional[int] = None) -> int:
+    """Scoped-VMEM request: the gathered rows plus the double-buffered
+    blocks that move per row of the tile (``row_words`` float32 words: by
+    default the output row and a lane-padded weight row), with headroom for
+    Mosaic's own temporaries."""
+    if row_words is None:
+        row_words = fp + LANES
+    need = 4 * (SLOTS_PER_STEP * fp + 2 * block_rows * row_words)
     return max(32 * 2**20, 2 * need)
 
 
@@ -187,28 +253,57 @@ def kernel_slots(r: int, k: int) -> int:
     return rp * kp
 
 
+def _pad_heads(x, heads: int):
+    """``x`` in float32 with each head's columns zero-padded to whole lanes:
+    ``[N, H * dh]`` becomes ``[N, H * dhp]``, dhp the multiple of 128 at
+    or above dh, so a head is a lane-aligned column group of every row.
+    Returns the padded array and its width."""
+    n, f = x.shape
+    if f % heads:
+        raise ValueError(f"width {f} is not a multiple of {heads} heads")
+    dhp = _round_up(f // heads, LANES)
+    fp = heads * dhp
+    if heads == 1:
+        return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, fp - f))), fp
+    xh = x.astype(jnp.float32).reshape(n, heads, f // heads)
+    return jnp.pad(xh, ((0, 0), (0, 0), (0, dhp - f // heads))).reshape(n, fp), fp
+
+
+def _slot_major_ids(ell_idx, nt: int, br: int, nk: int, kc: int):
+    """The padded ``[rp, kp]`` ids as one 1-D array in which step (i, c)
+    reads block ``i * nk + c``, slot-major within the block."""
+    r, k = ell_idx.shape
+    idx = jnp.pad(ell_idx.astype(jnp.int32), ((0, nt * br - r), (0, nk * kc - k)))
+    return idx.reshape(nt, br, nk, kc).transpose(0, 2, 3, 1).reshape(-1)
+
+
 def _seg_aggregate_pallas(x, ell_idx, ell_w, *, block_k: Optional[int],
                           interpret: bool):
     n, f = x.shape
     r, k = ell_idx.shape
+    heads = ell_w.shape[2] if ell_w.ndim == 3 else 1
     if r == 0 or k == 0 or n == 0:
         return jnp.zeros((r, f), x.dtype)
     kc, br, rp, kp = _launch_shape(r, k, block_k)
-    fp = _round_up(f, LANES)
     nt, nk = rp // br, kp // kc
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, fp - f)))
-    idx = jnp.pad(ell_idx.astype(jnp.int32), ((0, rp - r), (0, kp - k)))
-    w = jnp.pad(ell_w.astype(jnp.float32), ((0, rp - r), (0, kp - k)))
-    # Step (i, c) reads idx block i*nk + c, slot-major within the block.
-    idx = idx.reshape(nt, br, nk, kc).transpose(0, 2, 3, 1).reshape(-1)
-    w = w.reshape(rp, nk, kc).transpose(1, 0, 2)  # [nk, rp, kc]
+    xp, fp = _pad_heads(x, heads)
+    idx = _slot_major_ids(ell_idx, nt, br, nk, kc)
+    pad = ((0, rp - r), (0, kp - k)) + ((0, 0),) * (ell_w.ndim - 2)
+    w = jnp.pad(ell_w.astype(jnp.float32), pad)
+    if heads == 1:
+        w = w.reshape(rp, nk, kc).transpose(1, 0, 2)  # [nk, rp, kc]
+        w_spec = pl.BlockSpec((None, br, kc), lambda i, c: (c, i, 0))
+    else:
+        w = w.reshape(rp, nk, kc, heads).transpose(1, 3, 0, 2)  # [nk, H, rp, kc]
+        w_spec = pl.BlockSpec((None, heads, br, kc), lambda i, c: (c, 0, i, 0))
     out = pl.pallas_call(
-        functools.partial(_seg_aggregate_kernel, block_rows=br, block_k=kc),
+        functools.partial(_seg_aggregate_kernel, block_rows=br, block_k=kc,
+                          heads=heads),
         grid=(nt, nk),
         in_specs=[
             pl.BlockSpec((SLOTS_PER_STEP,), lambda i, c: (i * nk + c,),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, br, kc), lambda i, c: (c, i, 0)),
+            w_spec,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((br, fp), lambda i, c: (i, 0)),
@@ -217,11 +312,13 @@ def _seg_aggregate_pallas(x, ell_idx, ell_w, *, block_k: Optional[int],
                         pltpu.SemaphoreType.DMA((1,))],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(br, fp)),
+            vmem_limit_bytes=_vmem_limit(br, fp, fp + heads * LANES)),
         interpret=interpret,
         name="seg_aggregate",
     )(idx, w, xp.reshape(n, 1, fp))
-    return out[:r, :f].astype(x.dtype)
+    if heads == 1:
+        return out[:r, :f].astype(x.dtype)
+    return out[:r].reshape(r, heads, -1)[:, :, :f // heads].reshape(r, f).astype(x.dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -242,7 +339,7 @@ def _batchable(block_k: Optional[int], interpret: bool):
             n = x.shape[1]
             idx = idx + (jnp.arange(axis_size, dtype=idx.dtype) * n)[:, None, None]
             x = x.reshape(axis_size * n, x.shape[2])
-        out = fn(x, idx.reshape(axis_size * r, k), w.reshape(axis_size * r, k))
+        out = fn(x, idx.reshape(axis_size * r, k), w.reshape(axis_size * r, *w.shape[2:]))
         return out.reshape(axis_size, r, out.shape[-1]), True
 
     return fn
@@ -252,12 +349,18 @@ def _batchable(block_k: Optional[int], interpret: bool):
 def seg_aggregate(
     x: jax.Array,        # [N, F]
     ell_idx: jax.Array,  # [R, K] int32
-    ell_w: jax.Array,    # [R, K] f32 (0 padding)
+    ell_w: jax.Array,    # [R, K] f32 (0 padding), or [R, K, H] per head
     *,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """out[r] = sum_k ell_w[r,k] * x[ell_idx[r,k]] via pallas_call.
+
+    With ``[R, K, H]`` weights F holds H equal column groups (heads) and
+    ``out[r, head h] = sum_k ell_w[r,k,h] * x[ell_idx[r,k], head h]``: one
+    row copy per slot serves every head. The wrapper pads each head to
+    whole lanes (``_pad_heads``), so a head's columns are a lane-aligned
+    slice of the tile.
 
     ``block_k`` overrides the slot chunk per grid step (a power of two up
     to 128; default from K). ``interpret=None`` compiles on a TPU and
@@ -266,6 +369,63 @@ def seg_aggregate(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _batchable(block_k, bool(interpret))(x, ell_idx, ell_w)
+
+
+def _seg_sddmm_pallas(g, x, ell_idx, ell_w, *, heads: int, interpret: bool):
+    n, f = x.shape
+    r, k = ell_idx.shape
+    if r == 0 or k == 0 or n == 0:
+        return jnp.zeros((r, k, heads), jnp.float32)
+    kc, br, rp, kp = _launch_shape(r, k, None)
+    nt, nk = rp // br, kp // kc
+    xp, fp = _pad_heads(x, heads)
+    gp = jnp.pad(_pad_heads(g, heads)[0], ((0, rp - r), (0, 0)))
+    w = jnp.pad(ell_w.astype(jnp.float32), ((0, rp - r), (0, kp - k)))
+    w = w.reshape(rp, nk, kc).transpose(1, 0, 2)  # [nk, rp, kc]
+    out = pl.pallas_call(
+        functools.partial(_seg_sddmm_kernel, block_rows=br, block_k=kc,
+                          heads=heads),
+        grid=(nt, nk),
+        in_specs=[
+            pl.BlockSpec((SLOTS_PER_STEP,), lambda i, c: (i * nk + c,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, br, kc), lambda i, c: (c, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((br, fp), lambda i, c: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, heads, br, kc), lambda i, c: (c, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nk, heads, rp, kc), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((kc, br, 1, fp), jnp.float32),
+                        pltpu.SemaphoreType.DMA((1,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(br, fp, 2 * fp + (heads + 1) * LANES)),
+        interpret=interpret,
+        name="seg_sddmm",
+    )(_slot_major_ids(ell_idx, nt, br, nk, kc), w, xp.reshape(n, 1, fp), gp)
+    return out.transpose(2, 0, 3, 1).reshape(rp, kp, heads)[:r, :k]
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def seg_sddmm(
+    g: jax.Array,        # [R, F] destination rows
+    x: jax.Array,        # [N, F] source rows
+    ell_idx: jax.Array,  # [R, K] int32
+    ell_w: jax.Array,    # [R, K] f32 (0 padding)
+    *,
+    heads: int,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """out[r, k, h] = <g[r, head h], x[ell_idx[r, k], head h]> via
+    pallas_call, 0 on padding slots: the sampled dense-dense product
+    (SDDMM) that gives an attention-weighted aggregation's gradient with
+    respect to its slot weights. A head is the h-th of F's ``heads`` equal
+    column groups. Same fetch loop and launch shape as
+    :func:`seg_aggregate`; ``interpret`` as there."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _seg_sddmm_pallas(g, x, ell_idx, ell_w, heads=heads,
+                             interpret=bool(interpret))
 
 
 # --------------------------------------------------------------------------
@@ -333,20 +493,49 @@ def _bucket_matvec(x: jax.Array, b: DeviceEllBucket, kernel: bool) -> jax.Array:
 
 
 def _bucketed_forward(x: jax.Array, ell: DeviceBucketedEll, out_rows: int,
-                      kernel: bool) -> jax.Array:
-    """out[rows_b] += seg_aggregate(x, idx_b, w_b) for every degree bucket.
+                      kernel: bool, weights=None) -> jax.Array:
+    """out[rows_b] += seg_aggregate(x, idx_b, w_b) for every degree bucket,
+    with ``weights[b]`` (``[Rb, K]`` or ``[Rb, K, H]``) in place of the
+    layout's own ``w_b`` where given.
 
     Padding bucket rows carry all-zero weights and scatter a zero into row
     0, so the R-row (not nnz-row) scatter is the only irregular access.
     """
     out = jnp.zeros((out_rows, x.shape[-1]), x.dtype)
-    for b in ell.buckets:
+    for i, b in enumerate(ell.buckets):
+        if weights is not None:
+            b = b._replace(w=weights[i])
         with jax.named_scope(f"k{b.idx.shape[-1]}"):
             out = out.at[b.rows].add(_bucket_matvec(x, b, kernel))
     return out
 
 
-def _zero_cotangents(tree):
+def bucketed_weighted_sum(x: jax.Array, ell: DeviceBucketedEll, weights,
+                          out_rows: int, *, use_kernel="auto") -> jax.Array:
+    """The bucketed aggregation with slot weights the caller computes:
+    ``weights[b]`` is ``[Rb, K, H]`` for bucket b, and head h of row r sums
+    ``weights[b][r, k, h]`` times head h of source row ``idx_b[r, k]``.
+    Linear in ``x`` and in the weights; no VJP of its own (the attention
+    op that calls it has one)."""
+    return _bucketed_forward(x, ell, out_rows, _use_kernel(use_kernel), weights)
+
+
+def bucketed_sddmm(g: jax.Array, x: jax.Array, ell: DeviceBucketedEll,
+                   heads: int, *, use_kernel="auto"):
+    """Per degree bucket, ``[Rb, K, H]`` per-head dot products of each
+    row's ``g`` (taken at the bucket's destination rows) with the source
+    row of each slot; 0 on padding slots."""
+    kernel = _use_kernel(use_kernel)
+    out = []
+    for b in ell.buckets:
+        with jax.named_scope(f"k{b.idx.shape[-1]}"):
+            gb = g[b.rows]
+            out.append(seg_sddmm(gb, x, b.idx, b.w, heads=heads) if kernel
+                       else ref.seg_sddmm_ref(gb, x, b.idx, b.w, heads))
+    return out
+
+
+def zero_cotangents(tree):
     """Symbolic-zero cotangents for a layout pytree (float0 for ints)."""
     return jax.tree_util.tree_map(
         lambda a: np.zeros(np.shape(a), jax.dtypes.float0)
@@ -372,7 +561,7 @@ def _bucketed_aggregate_bwd(out_rows, in_rows, kernel, res, g):
     # pattern, reverse-graph layout.
     with jax.named_scope("agg_bwd"):
         dx = _bucketed_forward(g, ell_t, in_rows, kernel)
-    return dx, _zero_cotangents(ell), _zero_cotangents(ell_t)
+    return dx, zero_cotangents(ell), zero_cotangents(ell_t)
 
 
 _bucketed_aggregate.defvjp(_bucketed_aggregate_fwd, _bucketed_aggregate_bwd)

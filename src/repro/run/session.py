@@ -61,7 +61,15 @@ def build_graph(spec: RunSpec) -> Tuple[Any, np.ndarray]:
         g = g.mean_normalized()
     elif gs.norm == "gcn":
         g = g.gcn_normalized()
+    elif _attends_self(spec):
+        g = g.add_self_loops()
     return g, x
+
+
+def _attends_self(spec: RunSpec) -> bool:
+    """GAT attends over N(i) and i itself; the mean and gcn norms add the
+    self-loops already, ``none`` does not."""
+    return spec.model.model == "gat" and spec.graph.norm == "none"
 
 
 def build_partition(spec: RunSpec, g) -> Any:
@@ -149,11 +157,12 @@ class BuildCache:
 
     @staticmethod
     def _graph_key(spec: RunSpec) -> str:
-        return spec.graph.content_hash()
+        loops = "|self-loops" if _attends_self(spec) else ""
+        return spec.graph.content_hash() + loops
 
-    @staticmethod
-    def _part_key(spec: RunSpec) -> str:
-        return f"{spec.graph.content_hash()}|{spec.partition.content_hash()}"
+    @classmethod
+    def _part_key(cls, spec: RunSpec) -> str:
+        return f"{cls._graph_key(spec)}|{spec.partition.content_hash()}"
 
     def graph(self, spec: RunSpec) -> Tuple[Any, np.ndarray]:
         key = self._graph_key(spec)
@@ -373,7 +382,7 @@ def build_session(spec: RunSpec, cache: Optional[BuildCache] = None
     from repro.core.trainer import (_lift_worker_data,
                                     prepare_distributed_host)
 
-    spec = resolve_auto(spec.validate())
+    spec = resolve_auto(spec.validate()).validate_training()
     with trace.span("build"):
         with trace.span("graph"):
             g, x = cache.graph(spec) if cache is not None else build_graph(spec)
@@ -381,7 +390,8 @@ def build_session(spec: RunSpec, cache: Optional[BuildCache] = None
             pg = (cache.partition(spec, g) if cache is not None
                   else build_partition(spec, g))
         with trace.span("host_data"):
-            hwd = prepare_distributed_host(g, x, pg)
+            hwd = prepare_distributed_host(g, x, pg,
+                                           attention=spec.model.model == "gat")
         if spec.exec.mode == "multiproc":
             # The host arrays ARE the runtime's shared store; workers device-
             # materialize their own slices, the parent never lifts anything.

@@ -50,6 +50,11 @@ FEATURE_SOURCES: Registry = Registry("feature source")
 _WIRE_BITS = (0, 2, 4, 8)
 
 
+# Field metadata of a key added after artifacts were recorded: the
+# canonical form (and so the content hash) leaves it out at its default.
+OMIT_DEFAULT = {"omit_default": True}
+
+
 class SpecError(ValueError):
     """A RunSpec (or an override applied to one) is invalid."""
 
@@ -93,7 +98,13 @@ class _SubSpec:
     """Shared dict/JSON plumbing for the frozen sub-spec dataclasses."""
 
     def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
+        """The fields, less each one marked ``OMIT_DEFAULT`` that holds its
+        default: a key added to the schema then moves no recorded hash."""
+        d = dataclasses.asdict(self)
+        for f in fields(self):
+            if f.metadata.get("omit_default") and d[f.name] == f.default:
+                del d[f.name]
+        return d
 
     def content_hash(self) -> str:
         """Stable short id of this sub-spec's content, prefixed by the
@@ -293,12 +304,16 @@ class ModelSpec(_SubSpec):
     norm: str = "layer"        # layer | none
     label_prop: bool = True
     lp_rate: float = 0.5
-    gat_heads: int = 4
+    gat_heads: int = 4         # GAT hidden layers: heads, concatenated
+    gat_out_heads: int = field(default=1, metadata=OMIT_DEFAULT)  # averaged
 
     def validate(self) -> None:
         if self.model not in ("gcn", "sage", "gin", "gat"):
             raise SpecError(f"model.model must be gcn|sage|gin|gat, "
                             f"got {self.model!r}")
+        if self.gat_heads < 1 or self.gat_out_heads < 1:
+            raise SpecError("model.gat_heads and model.gat_out_heads must be "
+                            f">= 1, got {self.gat_heads}, {self.gat_out_heads}")
         if self.num_layers < 1:
             raise SpecError(f"model.num_layers must be >= 1, "
                             f"got {self.num_layers}")
@@ -313,7 +328,7 @@ class ModelSpec(_SubSpec):
             num_layers=self.num_layers, dropout=self.dropout,
             norm=self.norm, label_prop=self.label_prop,
             lp_rate=self.lp_rate, quant_bits=schedule.bits,
-            gat_heads=self.gat_heads)
+            gat_heads=self.gat_heads, gat_out_heads=self.gat_out_heads)
 
 
 @dataclass(frozen=True)
@@ -385,6 +400,32 @@ class RunSpec:
                 f"got nprocs={self.exec.nprocs} with "
                 f"partition.nparts={self.partition.nparts} (use 0 to "
                 "inherit nparts)")
+        return self
+
+    def validate_training(self) -> "RunSpec":
+        """Refuse what the trainer cannot train as asked, rather than train
+        something else. GAT's attention needs both ends of every edge on
+        one worker: with more workers the halo would have to carry ``W x``
+        and ``e_src`` of the boundary sources (post-aggregation), which the
+        exchange does not do; and its kernels run under ``shard_map`` on
+        the bucketed layout, with no stale halo to reuse."""
+        if self.model.model == "gat":
+            why = {
+                "partition.nparts": (self.partition.nparts == 1
+                                     and not self.partition.hierarchical),
+                "exec.mode": self.exec.mode == "shard_map",
+                "schedule.agg_backend": self.schedule.agg_backend == "ell",
+                "schedule.cd": self.schedule.cd == 1,
+            }
+            bad = [k for k, ok in why.items() if not ok]
+            if bad:
+                raise SpecError(
+                    f"model.model=gat trains only as one worker (partition."
+                    f"nparts=1, flat) under exec.mode=shard_map with "
+                    f"schedule.agg_backend=ell and schedule.cd=1; {bad} "
+                    "differ. With more workers the halo would have to carry "
+                    "W x and e_src of boundary sources (post-aggregation), "
+                    "which the exchange does not do.")
         return self
 
     # -- dict / JSON round-trip -------------------------------------------

@@ -165,14 +165,14 @@ class GNNServer:
 
     def _forward(self, params, x, labels, prop_mask, ell):
         n = x.shape[0]
-        if self.cfg.model == "gat":
-            agg = lambda l, h: L.gat_aggregate_bucketed(
-                params["layers"][l], h, ell, n, self.cfg.gat_heads)
-        else:
+
+        def agg(l, h, scores=None):
             # Forward-only: the reverse layout is only consumed by the
             # VJP, so the forward layout stands in for both arguments.
-            agg = lambda l, h: bucketed_aggregate(h, ell, ell, n,
-                                                  use_kernel="auto")
+            if scores is not None:  # GAT: h is W x, weighted by attention
+                return L.gat_attention(h, *scores, ell)
+            return bucketed_aggregate(h, ell, ell, n, use_kernel="auto")
+
         return M.forward(params, self.cfg, x, labels, prop_mask, agg,
                          train=False)
 

@@ -126,10 +126,11 @@ def _on_duration(event: str, seconds: float, **kw) -> None:
 
 
 # The ``jax.named_scope`` names the training step uses (core/model.py,
-# core/trainer.py, core/exchange.py, kernels/seg_aggregate.py).
+# core/layers.py, core/trainer.py, core/exchange.py, kernels/seg_aggregate.py).
 PROGRAM_SCOPE = re.compile(
     r"label_prop|loss|layer\d+|norm|dropout|aggregate|update|exchange_issue"
-    r"|local|exchange_finalize|k\d+|agg_bwd|quantize|dequantize")
+    r"|local|exchange_finalize|k\d+|agg_bwd|quantize|dequantize|attention"
+    r"|sddmm")
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?op_name=\"([^\"]*)\"", re.M)
 
 

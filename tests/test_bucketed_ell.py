@@ -14,7 +14,7 @@ from repro.core import (
     prepare_distributed,
 )
 from repro.core.exchange import scatter_recv, stack_halo_plan
-from repro.core.layers import gat_aggregate, gat_aggregate_bucketed, init_layer
+from repro.core.layers import gat_aggregate, gat_attention, gat_scores, init_layer
 from repro.graph import (
     build_hierarchical_partitioned_graph,
     build_partitioned_graph,
@@ -25,6 +25,7 @@ from repro.graph.structure import (
     bucketed_ell_from_csr,
     coo_to_csr,
     ell_from_csr,
+    reverse_slot_index,
     stack_bucketed_ells,
     transpose_csr,
 )
@@ -302,9 +303,85 @@ class TestGATSharedLayout:
             stack_bucketed_ells([bucketed_ell_from_csr(csr)]), squeeze=True)
         p = init_layer(jax.random.PRNGKey(0), "gat", 8, 16, heads=4)
         h = jax.random.normal(jax.random.PRNGKey(1), (g.num_nodes, 8))
-        dense = gat_aggregate(p, h, jnp.asarray(idx), jnp.asarray(valid), 4)
-        bucketed = gat_aggregate_bucketed(p, h, ell, g.num_nodes, 4)
+        wx = h @ p["w"]
+        scores = gat_scores(p, wx)
+        dense = gat_aggregate(wx, *scores, jnp.asarray(idx), jnp.asarray(valid))
+        bucketed = gat_attention(wx, *scores, ell)
         np.testing.assert_allclose(bucketed, dense, rtol=1e-4, atol=1e-5)
+
+
+def _gat_layouts(g, workers=1):
+    """Forward and reverse bucketed layouts of ``g`` (stacked for
+    ``workers`` copies of it) and the reverse slot index."""
+    csr = g.csr_by_dst()
+    fwd = stack_bucketed_ells([bucketed_ell_from_csr(csr)] * workers)
+    rev = stack_bucketed_ells([bucketed_ell_from_csr(transpose_csr(csr))] * workers)
+    return fwd, rev, reverse_slot_index(fwd, rev)
+
+
+class TestReverseSlotIndex:
+    @pytest.mark.parametrize("workers", [1, 3])
+    def test_forward_values_reach_the_transpose_slot_order(self, workers):
+        """A value set on each forward slot as a function of its edge,
+        gathered through the index, lands on the reverse slot of the same
+        edge; padding gathers the appended zero."""
+        g = rmat_graph(7, edge_factor=4, seed=2).dedupe().mean_normalized()
+        fwd, rev, slot_t = _gat_layouts(g, workers)
+        n = g.num_nodes
+        edge_value = lambda dst, src: 1.0 + dst * n + src   # never 0
+        flat = np.concatenate([
+            np.where(w != 0, edge_value(rows[..., None], idx), 0.0).reshape(workers, -1)
+            for _, rows, idx, w in fwd] + [np.zeros((workers, 1))], axis=1)
+        for (_, rows_t, idx_t, w_t), s in zip(rev, slot_t):
+            assert s.shape == idx_t.shape and s.dtype == np.int32
+            got = np.take_along_axis(flat, s.reshape(workers, -1), axis=1).reshape(s.shape)
+            want = np.where(w_t != 0, edge_value(idx_t, rows_t[..., None]), 0.0)
+            np.testing.assert_array_equal(got, want)
+
+    def test_an_edge_missing_from_the_forward_layout_is_refused(self):
+        g = rmat_graph(6, edge_factor=4, seed=3).dedupe().mean_normalized()
+        fwd, rev, _ = _gat_layouts(g)
+        k, rows, idx, w = fwd[-1]
+        w = w.copy()
+        w[0, 0, 0] = 0.0
+        with pytest.raises(ValueError, match="does not"):
+            reverse_slot_index(fwd[:-1] + [(k, rows, idx, w)], rev)
+
+
+class TestGATAttentionOp:
+    """The bucketed attention op (custom VJP: weighted kernel over the
+    reverse layout, SDDMM, softmax backward) against JAX's autodiff of the
+    dense max-degree ELL form."""
+
+    @pytest.mark.parametrize("use_kernel", [False, True])
+    @pytest.mark.parametrize("heads,dh", [(4, 8), (3, 5)])
+    def test_value_and_grad_match_dense_ell(self, use_kernel, heads, dh):
+        g = rmat_graph(6, edge_factor=4, seed=5).mean_normalized()
+        n = g.num_nodes
+        idx, _, valid = ell_from_csr(g.csr_by_dst())
+        fwd, rev, slot_t = _gat_layouts(g)
+        ell, ell_t = (device_bucketed(s, squeeze=True) for s in (fwd, rev))
+        slot_t = tuple(jnp.asarray(a[0]) for a in slot_t)
+        kx, ks, kd, kc = jax.random.split(jax.random.PRNGKey(heads), 4)
+        wx = jax.random.normal(kx, (n, heads * dh))
+        e_src = jax.random.normal(ks, (n, heads))
+        e_dst = jax.random.normal(kd, (n, heads))
+        cot = jax.random.normal(kc, (n, heads * dh))
+
+        def dense(wx, es, ed):
+            return gat_aggregate(wx, es, ed, jnp.asarray(idx), jnp.asarray(valid))
+
+        def op(wx, es, ed):
+            return gat_attention(wx, es, ed, ell, ell_t, slot_t,
+                                 use_kernel=use_kernel)
+
+        np.testing.assert_allclose(jax.jit(op)(wx, e_src, e_dst),
+                                   dense(wx, e_src, e_dst), rtol=1e-5, atol=1e-5)
+        loss = lambda f: lambda *a: jnp.vdot(f(*a), cot)
+        got = jax.jit(jax.grad(loss(op), argnums=(0, 1, 2)))(wx, e_src, e_dst)
+        want = jax.grad(loss(dense), argnums=(0, 1, 2))(wx, e_src, e_dst)
+        for name, a, b in zip(("wx", "e_src", "e_dst"), got, want):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4, err_msg=name)
 
 
 class TestTrainerParity:

@@ -46,7 +46,7 @@ class TestDistributedEquivalence:
         gn = g.mean_normalized()
         params = init_params(jax.random.PRNGKey(0), cfg)
         data = prepare_single(g, x)
-        agg = make_single_agg_fn(cfg, data, lambda: params)
+        agg = make_single_agg_fn(data)
         logits_single = M.forward(params, cfg, data.x, data.labels,
                                   jnp.zeros(g.num_nodes, bool), agg)
 
@@ -158,6 +158,14 @@ class TestTraining:
         _, hist = train_gcn_single(g, x, cfg, epochs=25, lr=0.01, log_every=25)
         assert hist[-1]["eval_acc"] > 0.85
 
+    def test_single_device_gat_learns(self, sbm_setup):
+        """GAT on the single-device path: the bucketed attention op, 4
+        concatenated hidden heads and 2 averaged output heads."""
+        g, x = sbm_setup
+        cfg = _cfg(model="gat", gat_heads=4, gat_out_heads=2, norm="none")
+        _, hist = train_gcn_single(g, x, cfg, epochs=25, lr=0.005, log_every=25)
+        assert hist[-1]["eval_acc"] > 0.85
+
     @pytest.mark.parametrize("bits", [0, 2])
     def test_distributed_learns(self, sbm_setup, bits):
         g, x = sbm_setup
@@ -196,7 +204,7 @@ class TestMaskedLabelProp:
         cfg = _cfg(label_prop=True)
         params = init_params(jax.random.PRNGKey(0), cfg)
         data = prepare_single(g, x)
-        agg = make_single_agg_fn(cfg, data, lambda: params)
+        agg = make_single_agg_fn(data)
         no_prop = M.forward(params, cfg, data.x, data.labels,
                             jnp.zeros(g.num_nodes, bool), agg)
         with_prop = M.forward(params, cfg, data.x, data.labels,

@@ -9,7 +9,63 @@ from hypothesis_compat import given, settings, st
 from repro.kernels import aggregate, dequantize_unpack, quantize_pack
 from repro.kernels import ref
 from repro.kernels.quant_pack import dequant_unpack, quant_pack
-from repro.kernels.seg_aggregate import seg_aggregate
+from repro.kernels.seg_aggregate import seg_aggregate, seg_sddmm
+
+
+
+def _heads_case(heads, dh, n=60, r=20, k=12, seed=0):
+    """Features of ``heads`` column groups of ``dh``, an [r, k] layout with
+    about a third of its slots padding, and per-head slot weights."""
+    kx, ki, kw, km, kg = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(kx, (n, heads * dh))
+    idx = jax.random.randint(ki, (r, k), 0, n)
+    mask = (jax.random.uniform(km, (r, k)) > 0.3).astype(jnp.float32)
+    w = jax.random.uniform(kw, (r, k, heads)) * mask[..., None]
+    g = jax.random.normal(kg, (r, heads * dh))
+    return x, idx, mask, w, g
+
+
+class TestSegAggregateHeads:
+    """The per-head form of the kernel: one row copy per slot, each head's
+    lane-aligned column group weighted by its own slot weight."""
+
+    @pytest.mark.parametrize("heads,dh", [(1, 100), (4, 32), (4, 128), (6, 47)])
+    def test_matches_per_head_sums(self, heads, dh):
+        x, idx, _, w, _ = _heads_case(heads, dh, seed=heads + dh)
+        out = seg_aggregate(x, idx, w, interpret=True)
+        xh = np.asarray(x).reshape(x.shape[0], heads, dh)
+        want = np.einsum("rkh,rkhd->rhd", np.asarray(w), xh[np.asarray(idx)])
+        np.testing.assert_allclose(out, want.reshape(out.shape), rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(out, ref.seg_aggregate_ref(x, idx, w),
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_one_head_is_the_scalar_weight_kernel_bit_for_bit(self):
+        x, idx, mask, w, _ = _heads_case(1, 256, seed=3)
+        scalar = seg_aggregate(x, idx, w[..., 0], interpret=True)
+        headed = seg_aggregate(x, idx, w, interpret=True)
+        assert np.array_equal(np.asarray(headed), np.asarray(scalar))
+
+    def test_zero_weight_heads_add_exact_zeros(self):
+        """A slot whose weight is 0 in one head and not another is fetched
+        once; the zero head adds nothing, whatever the row holds."""
+        x, idx, mask, w, _ = _heads_case(4, 32, seed=5)
+        w = w.at[:, :, 2].set(0.0)
+        x = x.at[idx[0, 0]].set(jnp.inf)
+        w = w.at[0, 0].set(jnp.array([0.5, 0.25, 0.0, 1.0]))
+        out = np.asarray(seg_aggregate(x, idx, w, interpret=True))
+        assert np.all(out[:, 64:96] == 0.0)
+
+    @pytest.mark.parametrize("heads,dh", [(1, 100), (4, 32), (6, 47)])
+    def test_sddmm_is_the_per_slot_dot_products(self, heads, dh):
+        x, idx, mask, _, g = _heads_case(heads, dh, seed=7 + heads)
+        out = seg_sddmm(g, x, idx, mask, heads=heads, interpret=True)
+        xh = np.asarray(x).reshape(x.shape[0], heads, dh)[np.asarray(idx)]
+        gh = np.asarray(g).reshape(g.shape[0], heads, dh)
+        want = np.einsum("rhd,rkhd->rkh", gh, xh) * np.asarray(mask)[..., None]
+        assert out.shape == (idx.shape[0], idx.shape[1], heads)
+        np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(out, ref.seg_sddmm_ref(g, x, idx, mask, heads),
+                                   rtol=1e-5, atol=1e-4)
 
 
 class TestSegAggregate:

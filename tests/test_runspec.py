@@ -85,6 +85,19 @@ class TestContentHash:
         # rehashing from rs-58ae58fdfdbc.)
         assert RunSpec().content_hash() == "rs-f356a4f93c9f"
 
+    def test_key_added_at_its_default_keeps_the_hash(self):
+        """``model.gat_out_heads`` (marked OMIT_DEFAULT) is left out of the
+        canonical form while at its default, so recorded hashes stand; set,
+        it counts like any field and round-trips."""
+        spec = tiny_spec()
+        assert "gat_out_heads" not in spec.to_dict()["model"]
+        assert spec.content_hash() == spec.with_overrides(
+            ["model.gat_out_heads=1"]).content_hash()
+        six = spec.with_overrides(["model.gat_out_heads=6"])
+        assert six.to_dict()["model"]["gat_out_heads"] == 6
+        assert six.content_hash() != spec.content_hash()
+        assert RunSpec.from_json(six.to_json()) == six
+
     def test_sub_spec_hashes(self):
         # Per-section hashes: kind-prefixed, content-addressed, and only
         # sensitive to their own section.

@@ -12,6 +12,7 @@ running this file loads the TPU library.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +105,58 @@ def test_bucketed_aggregate_grad_compiles(one_chip, compiled_kernel, workers):
     text = jax.jit(jax.grad(loss)).lower(x, ell, ell_t).compile().as_text()
     # 3 buckets forward + 3 on the reverse layout in the backward.
     assert text.count('custom_call_target="tpu_custom_call"') >= 6
+
+
+# GAT's attention at the published widths: 4 heads of 256 (hidden layers)
+# and 6 heads of 47, padded to 128 lanes each (output layer).
+@pytest.mark.parametrize("heads,dh", [(4, 256), (6, 47)])
+@pytest.mark.parametrize("k", [1, 64])
+def test_seg_aggregate_heads_compiles(one_chip, heads, dh, k):
+    fn = functools.partial(sa.seg_aggregate, interpret=False)
+    r = ROWS[k]
+    _compile(fn, one_chip, ((N, heads * dh), jnp.float32), ((r, k), jnp.int32),
+             ((r, k, heads), jnp.float32))
+
+
+@pytest.mark.parametrize("heads,dh", [(4, 256), (6, 47)])
+@pytest.mark.parametrize("k", [1, 64])
+def test_seg_sddmm_compiles(one_chip, heads, dh, k):
+    fn = functools.partial(sa.seg_sddmm, heads=heads, interpret=False)
+    r = ROWS[k]
+    text = _compile(fn, one_chip, ((r, heads * dh), jnp.float32),
+                    ((N, heads * dh), jnp.float32), ((r, k), jnp.int32),
+                    ((r, k), jnp.float32))
+    # A trace finds a kernel by its instruction's name (bench/trace_reduce.py
+    # matches by substring): this one must not read as the aggregation.
+    kernels = set(re.findall(r"%(seg_[a-z]+)[.\d]* = ", text))
+    assert kernels == {"seg_sddmm"}
+
+
+def test_gat_attention_grad_compiles(one_chip, monkeypatch):
+    """The attention op under ``jax.grad``: the weighted kernel over the
+    forward and reverse layouts and the SDDMM pass, with the kernels."""
+    from repro.core.layers import gat_attention
+    monkeypatch.setattr(sa, "seg_sddmm",
+                        functools.partial(sa.seg_sddmm, interpret=False))
+    monkeypatch.setattr(sa, "seg_aggregate",
+                        functools.partial(sa.seg_aggregate, interpret=False))
+    put = lambda t: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), t)
+    ell, ell_t = put(_layout(ROWS)), put(_layout(ROWS))
+    slot_t = put(tuple(jax.ShapeDtypeStruct((ROWS[k], k), jnp.int32) for k in ROWS))
+    heads, f = 4, 1024
+
+    def loss(wx, es, ed, ell, ell_t, slot_t):
+        out = gat_attention(wx, es, ed, ell, ell_t, slot_t, use_kernel=True)
+        return (out ** 2).sum()
+
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(N, f), shape(N, heads), shape(N, heads), ell, ell_t, slot_t
+    ).compile().as_text()
+    # 3 buckets forward, 3 over the reverse layout, 3 SDDMM.
+    assert text.count('custom_call_target="tpu_custom_call"') >= 9
+    assert "seg_sddmm" in text
 
 
 @pytest.mark.parametrize("bits", [2, 4, 8])
