@@ -53,7 +53,8 @@ from repro.graph.structure import (
 )
 from repro.kernels import aggregate as kernel_aggregate
 from repro.kernels import bucketed_aggregate, device_bucketed
-from repro.kernels.seg_aggregate import DeviceBucketedEll, bucketed_slots
+from repro.kernels.seg_aggregate import (DeviceBucketedEll, bucketed_copies,
+                                         bucketed_slots)
 from repro.kernels.ref import seg_aggregate_ref
 from repro.optim import adamw_init, adamw_update
 from repro.utils import trace
@@ -711,8 +712,9 @@ class DistributedTrainer:
         self._agg_counts = self._kernel_counts()
 
     def _kernel_counts(self) -> Dict[str, int]:
-        """Real edges (non-zero weights) and kernel slots (padding included)
-        of one epoch's bucketed aggregations: the local graph and every
+        """Real edges (non-zero weights), kernel slots (padding included)
+        and the row copies the kernel starts (``agg.copies``) of one
+        epoch's bucketed aggregations: the local graph and every
         receive scatter that has edges, each forward and backward in every
         layer; for GAT also ``sddmm.*``, the SDDMM pass over the forward
         layout in every layer. Empty when the step aggregates no bucketed
@@ -732,13 +734,16 @@ class DistributedTrainer:
         workers = int(np.prod(wd.x.shape[:-2]))
         fold = self.mode == "vmap"
         slots = sum(bucketed_slots(lay, workers, fold) for lay in layouts)
+        copies = sum(bucketed_copies(lay, workers, fold) for lay in layouts)
         edges = int(_count_nonzero([b.w for lay in layouts for b in lay.buckets]))
         layers = self.cfg.num_layers
-        counts = {"agg.edges": layers * edges, "agg.slots": layers * slots}
+        counts = {"agg.edges": layers * edges, "agg.slots": layers * slots,
+                  "agg.copies": layers * copies}
         if self.cfg.model == "gat":
             counts["sddmm.edges"] = layers * int(
                 _count_nonzero([b.w for b in wd.ell.buckets]))
             counts["sddmm.slots"] = layers * bucketed_slots(wd.ell, workers, fold)
+            counts["sddmm.copies"] = layers * bucketed_copies(wd.ell, workers, fold)
         return counts
 
     def _unreplicate(self, tree):

@@ -22,13 +22,15 @@ grid step receives its 1024 source-row ids as an SMEM block and issues one
 row DMA per slot into a VMEM tile, then accumulates the weighted rows in
 slot order. Slots of weight 0 that trail their column are not fetched: for
 each of the step's KC slot columns the kernel reduces the weight block to
-the row after the column's last nonzero weight and issues copies only up
-to there. The layout builder orders each degree bucket's rows by degree,
-highest first, so in every row tile each slot's real rows come first and
-all the ladder's padding trails. The time of a step goes to issuing copies
-and waiting on them on the scalar core, not to moving the rows, so the
-waits are taken eight rows at a time. A zero-weight slot before the tail
-is still fetched; every slot adds its row through a select on the weight,
+the row after the column's last nonzero weight, rounds that up to a whole
+group of eight rows, and issues copies only up to there. The layout
+builder orders each degree bucket's rows by degree, highest first, so in
+every row tile each slot's real rows come first and all the ladder's
+padding trails. The time of a step goes to issuing copies and waiting on
+them on the scalar core, not to moving the rows, so each turn of the start
+loop issues eight copies and each wait stands for eight rows. A
+zero-weight slot before the tail, or in the rounded-up group after it, is
+still fetched; every slot adds its row through a select on the weight,
 so a slot that was not fetched (its VMEM is stale) adds an exact zero, and
 the sum is the one a fetch-everything kernel gives for finite inputs. The
 one difference is at non-finite inputs: a NaN or Inf in a source row
@@ -109,7 +111,9 @@ from repro.kernels import ref
 SLOTS_PER_STEP = 1024
 LANES = 128
 MAX_BLOCK_K = 16
-# Row copies one semaphore wait stands for (a row tile has at least 8 rows).
+# Row copies started per turn of the start loop, and row copies one
+# semaphore wait stands for (a row tile has at least 8 rows).
+START_ROWS = 8
 WAIT_ROWS = 8
 
 
@@ -134,33 +138,43 @@ def _live_rows(w, heads: int = 1):
 def _fetch_rows(idx_ref, x_hbm, buf, sem, live, *, block_rows: int,
                 block_k: int):
     """Copy the step's source rows into ``buf`` (slot k's rows into
-    ``buf[k]``), each slot column up to its ``live`` row, and wait for
-    them all."""
+    ``buf[k]``), each slot column up to its ``live`` row rounded up to a
+    whole start group, and wait for them all.
+
+    The rows the round-up adds lie past the column's last nonzero weight:
+    their ids are valid rows (the layout pads with 0), and the select on
+    the weight drops what they copy, so the sum is unchanged. A row tile
+    holds a power of two rows, at least 8, so the groups stay inside it.
+    """
+    group = min(START_ROWS, block_rows)
+    wait_rows = min(WAIT_ROWS, group)
+
     def fetch_column(k):
-        """Start slot k's row copies up to its column's last nonzero weight."""
+        """Start slot k's row copies, ``group`` a loop turn."""
         col = k * block_rows
-        n = jnp.max(live[:, k:k + 1])
 
-        def start(r, carry):
-            pltpu.make_async_copy(x_hbm.at[pl.ds(idx_ref[col + r], 1)],
-                                  buf.at[k, pl.ds(r, 1)], sem.at[0]).start()
+        def start(g, carry):
+            # Written out by hand: Mosaic unrolls a fori_loop fully or not at all.
+            for j in range(group):
+                r = g * group + j
+                pltpu.make_async_copy(x_hbm.at[pl.ds(idx_ref[col + r], 1)],
+                                      buf.at[k, pl.ds(r, 1)], sem.at[0]).start()
             return carry
 
-        jax.lax.fori_loop(0, n, start, 0)
-        return n
+        groups = (jnp.max(live[:, k:k + 1]) + group - 1) // group
+        jax.lax.fori_loop(0, groups, start, 0)
+        return groups
 
-    def wait(rows):
-        # A wait takes the bytes of its descriptor off the semaphore: one
-        # wait on `rows` rows of the tile stands for that many row copies.
-        def body(i, carry):
-            rows_ref = buf.at[0, pl.ds(0, rows)]
-            pltpu.make_async_copy(rows_ref, rows_ref, sem.at[0]).wait()
-            return carry
-        return body
+    # A wait takes the bytes of its descriptor off the semaphore: one wait
+    # on ``wait_rows`` rows of the tile stands for that many row copies.
+    rows_ref = buf.at[0, pl.ds(0, wait_rows)]
 
-    started = sum(fetch_column(k) for k in range(block_k))
-    jax.lax.fori_loop(0, started // WAIT_ROWS, wait(WAIT_ROWS), 0)
-    jax.lax.fori_loop(0, started % WAIT_ROWS, wait(1), 0)
+    def wait(i, carry):
+        pltpu.make_async_copy(rows_ref, rows_ref, sem.at[0]).wait()
+        return carry
+
+    groups = sum(fetch_column(k) for k in range(block_k))
+    jax.lax.fori_loop(0, groups * (group // wait_rows), wait, 0)
 
 
 def _seg_aggregate_kernel(idx_ref, w_ref, x_hbm, out_ref, buf, sem, *,
@@ -251,6 +265,22 @@ def kernel_slots(r: int, k: int) -> int:
         return 0
     _, _, rp, kp = _launch_shape(r, k, None)
     return rp * kp
+
+
+def kernel_copies(w) -> int:
+    """Row copies one kernel launch over an ``[r, k]`` (or ``[r, k, H]``)
+    weight layout starts, by the kernel's rule: per grid step and slot
+    column, the row after the column's last nonzero weight (in any head),
+    rounded up to a whole group of ``START_ROWS``."""
+    r, k = w.shape[:2]
+    if r == 0 or k == 0:
+        return 0
+    kc, br, rp, kp = _launch_shape(r, k, None)
+    nz = np.zeros((rp, kp), bool)
+    nz[:r, :k] = (np.asarray(w) != 0).reshape(r, k, -1).any(axis=2)
+    nz = nz.reshape(rp // br, br, kp // kc, kc)
+    live = np.where(nz.any(axis=1), br - np.argmax(nz[:, ::-1], axis=1), 0)
+    return int(_round_up(live, min(START_ROWS, br)).sum())
 
 
 def _pad_heads(x, heads: int):
@@ -474,6 +504,19 @@ def bucketed_slots(ell: DeviceBucketedEll, workers: int, fold: bool) -> int:
         rows, k = b.idx.shape[-2:]
         total += (kernel_slots(workers * rows, k) if fold
                   else workers * kernel_slots(rows, k))
+    return total
+
+
+def bucketed_copies(ell: DeviceBucketedEll, workers: int, fold: bool) -> int:
+    """Row copies the kernel starts in one aggregation over a stacked
+    layout of ``workers`` worker graphs, launched as :func:`bucketed_slots`
+    says."""
+    total = 0
+    for b in ell.buckets:
+        w = np.asarray(b.w)
+        w = w.reshape(workers, *w.shape[-2:])
+        total += sum(kernel_copies(lw) for lw in
+                     ([w.reshape(-1, w.shape[-1])] if fold else w))
     return total
 
 

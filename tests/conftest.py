@@ -20,3 +20,25 @@ import pytest
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session")
+def copies_by_rule():
+    """Row copies the aggregation kernel starts over a stacked bucketed
+    layout, one launch per worker, counted tile by tile and column by
+    column: slot chunk KC the smallest power of two at or above K (at most
+    16), row tile 1024 / KC, and in each tile's column the row after the
+    last nonzero weight, rounded up to a group of 8."""
+    def count(ell) -> int:
+        total = 0
+        for b in ell.buckets:
+            for w in np.asarray(b.w).reshape(-1, *b.w.shape[-2:]):
+                r, k = w.shape
+                tile = 1024 // min(16, 1 << (k - 1).bit_length())
+                for r0 in range(0, r, tile):
+                    for c in range(k):
+                        rows = np.flatnonzero(w[r0:r0 + tile, c])
+                        if rows.size:
+                            total += -(-(int(rows[-1]) + 1) // 8) * 8
+        return total
+    return count

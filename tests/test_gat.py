@@ -104,6 +104,25 @@ def test_epoch_counts_the_sddmm_pass(gat):
     assert gat["build"]["spans"]["build/host_data"]["n"] == 1
 
 
+def test_epoch_counts_copies_by_the_kernel_rule(gat, copies_by_rule):
+    """``agg.copies`` over both layouts and ``sddmm.copies`` over the
+    forward one are the kernel's rule counted independently; the
+    round-up wastes at most 7 rows a column and grid step."""
+    s, c = gat["session"], gat["epochs"]["counters"]
+    epochs = gat["epochs"]["spans"]["epoch"]["n"]
+    wd = s.trainer.wd
+    fwd, rev = copies_by_rule(wd.ell), copies_by_rule(wd.ell_t)
+    assert c["agg.copies"] == epochs * 3 * (fwd + rev)
+    assert c["sddmm.copies"] == epochs * 3 * fwd
+    steps = {lay: epochs * 3 * sum(
+        sa.kernel_slots(*b.idx.shape[-2:]) * sa._block_k(b.idx.shape[-1])
+        // sa.SLOTS_PER_STEP for b in getattr(wd, lay).buckets)
+        for lay in ("ell", "ell_t")}
+    assert c["agg.edges"] <= c["agg.copies"] <= (
+        c["agg.edges"] + 7 * (steps["ell"] + steps["ell_t"]))
+    assert c["sddmm.edges"] <= c["sddmm.copies"] <= c["sddmm.edges"] + 7 * steps["ell"]
+
+
 def _launched_slots(jaxpr, kernel: str) -> int:
     """Slots of every ``pallas_call`` named ``kernel`` in a jaxpr."""
     total = 0

@@ -213,6 +213,75 @@ class TestSegAggregate:
         np.testing.assert_allclose(out, 3.0 * jnp.ones((5, 60)))
 
 
+# (form, heads, head width, block_k, K): the row tile BR is 1024 / KC,
+# KC = block_k or, for seg_sddmm, the default chunk of K.
+START_GROUP_FORMS = [
+    ("aggregate", 1, 100, 128, 128),  # BR 8
+    ("aggregate", 1, 100, 16, 16),    # BR 64
+    ("aggregate", 4, 32, 16, 16),
+    ("aggregate", 6, 47, 16, 16),
+    ("sddmm", 4, 32, None, 16),       # default launch shape: BR 64
+]
+
+
+def _start_group_cases():
+    for form, heads, dh, bk, k in START_GROUP_FORMS:
+        br = 1024 // (bk or min(16, k))
+        for live in sorted({0, 1, 7, 8, 9, br - 1, br} & set(range(br + 1))):
+            yield pytest.param(form, heads, dh, bk, k, live,
+                               id=f"{form}-{heads}x{dh}-br{br}-live{live}")
+
+
+@pytest.mark.parametrize("form,heads,dh,block_k,k,live", list(_start_group_cases()))
+def test_start_groups_round_up_per_column(form, heads, dh, block_k, k, live):
+    """Every slot column of both row tiles holds ``live`` real rows, then
+    weight-0 slots whose ids alternate between a NaN row and an Inf row.
+    The kernel starts copies in groups of 8 up to the live count rounded
+    up, so some of those rows are copied; they must add exact zeros. The
+    output matches the reference, the float32 sum in slot order within one
+    ulp a slot (seg_sddmm: the float32 per-slot dot products), and the
+    output with the poisoned rows zeroed, bit for bit."""
+    n = 64
+    rows = 2 * (1024 // (block_k or min(16, k)))
+    rng = np.random.default_rng(live + 131 * heads + rows)
+    real = np.broadcast_to(np.arange(rows)[:, None] % (rows // 2) < live, (rows, k))
+    idx = np.where(real, rng.integers(2, n, (rows, k)), np.arange(k) % 2).astype(np.int32)
+    w = np.where(real[..., None], rng.uniform(0.1, 1.0, (rows, k, heads)), 0.0)
+    w = w.astype(np.float32)
+    clean = rng.standard_normal((n, heads * dh)).astype(np.float32)
+    clean[:2] = 0.0
+    bad = clean.copy()
+    bad[0], bad[1] = np.nan, np.inf
+    if form == "sddmm":
+        mask = real.astype(np.float32)
+        g = rng.standard_normal((rows, heads * dh)).astype(np.float32)
+        run = lambda x: np.asarray(seg_sddmm(g, x, idx, mask, heads=heads,
+                                             interpret=True))
+        out = run(bad)
+        np.testing.assert_array_equal(out, run(clean))
+        assert (out[~real] == 0.0).all()
+        xh = clean.reshape(n, heads, dh)[idx]
+        want = np.einsum("rhd,rkhd->rkh", g.reshape(rows, heads, dh), xh) * mask[..., None]
+        np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(out, ref.seg_sddmm_ref(g, clean, idx, mask, heads),
+                                   rtol=1e-5, atol=1e-4)
+        return
+    w = w[..., 0] if heads == 1 else w
+    run = lambda x: np.asarray(seg_aggregate(x, idx, w, block_k=block_k,
+                                             interpret=True))
+    out = run(bad)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, run(clean))
+    np.testing.assert_allclose(out, ref.seg_aggregate_ref(clean, idx, w),
+                               rtol=1e-5, atol=1e-5)
+    wc = np.repeat(w.reshape(rows, k, heads), dh, axis=2)
+    slot_sum = lambda x, w: sum(w[:, j] * x[idx[:, j]] for j in range(k))
+    ulp = np.spacing(slot_sum(np.abs(clean), np.abs(wc)))
+    assert (np.abs(out - slot_sum(clean, wc)) <= k * ulp).all()
+    if live == 0:
+        assert (out == 0.0).all()
+
+
 class TestQuantPack:
     @pytest.mark.parametrize("bits", [2, 4, 8])
     @pytest.mark.parametrize("rows,feat", [(8, 32), (128, 256), (64, 48)])

@@ -63,16 +63,19 @@ def _compile(fn, sharding, *shapes):
 
 
 @pytest.mark.parametrize("workers", [0, 4])
-@pytest.mark.parametrize("k", [1, 8, 64])
+@pytest.mark.parametrize("k", [1, 8, 64, pytest.param((128, 128), id="128-block_k128")])
 @pytest.mark.parametrize("f", [256, 100])
 def test_seg_aggregate_compiles(one_chip, f, k, workers):
     """One bucket of the smoke's layout; ``workers=4`` vmaps it over the
-    four workers the one-chip path stacks (folded into one kernel call)."""
-    fn = functools.partial(sa.seg_aggregate, interpret=False)
+    four workers the one-chip path stacks (folded into one kernel call).
+    ``(K, block_k)`` sets the slot chunk: 128 leaves a row tile of 8, one
+    start group."""
+    k, block_k = k if isinstance(k, tuple) else (k, None)
+    fn = functools.partial(sa.seg_aggregate, block_k=block_k, interpret=False)
     lead = (workers,) if workers else ()
     if workers:
         fn = jax.vmap(fn)
-    r = ROWS[k]
+    r = ROWS.get(k, 40)
     _compile(fn, one_chip, (lead + (N, f), jnp.float32),
              (lead + (r, k), jnp.int32), (lead + (r, k), jnp.float32))
 

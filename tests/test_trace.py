@@ -143,6 +143,25 @@ def test_epoch_counts_edges_and_slots(single):
     assert c["agg.edges"] <= c["agg.slots"] == s.trainer._agg_counts["agg.slots"]
 
 
+def _column_steps(ell) -> int:
+    """(grid step, slot column) pairs of one aggregation over a
+    single-worker layout: each launch's slots over its row tile."""
+    return sum(sa.kernel_slots(*b.idx.shape[-2:]) * sa._block_k(b.idx.shape[-1])
+               // sa.SLOTS_PER_STEP for b in ell.buckets)
+
+
+def test_epoch_counts_copies_by_the_kernel_rule(single, copies_by_rule):
+    """``agg.copies`` is the kernel's rule counted independently, three
+    layers over both layouts; real slots lead every column of a bucket, so
+    the round-up wastes at most 7 rows a column and grid step."""
+    s, c = single["session"], single["epoch"]["counters"]
+    wd = s.trainer.wd
+    assert c["agg.copies"] == 3 * (copies_by_rule(wd.ell) + copies_by_rule(wd.ell_t))
+    assert c["agg.copies"] == s.trainer._agg_counts["agg.copies"]
+    steps = 3 * (_column_steps(wd.ell) + _column_steps(wd.ell_t))
+    assert c["agg.edges"] <= c["agg.copies"] <= c["agg.edges"] + 7 * steps
+
+
 def test_op_scopes_cover_every_layer_both_ways(single):
     scopes = single["scopes"]
     assert scopes["module"] == "jit_step_sm"
